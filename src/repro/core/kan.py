@@ -87,10 +87,11 @@ def kan_init(key: jax.Array, cfg: KANConfig,
     w_b = jax.random.uniform(
         k1, (cfg.n_in, cfg.n_out), dtype, -scale_b, scale_b
     )
-    # noise-scale init of c_i (KAN reference uses scale_noise=0.1 on grid)
-    t = 0.1 * scale_b * jax.random.normal(
+    # noise-scale init of c_i (KAN reference uses scale_noise=0.1 on grid);
+    # the numpy scale would promote a bf16 draw to f32, so cast back
+    t = (0.1 * scale_b * jax.random.normal(
         k2, (cfg.n_in, cfg.spec.n_bases, cfg.n_out), dtype
-    )
+    )).astype(dtype)
     return {"w_b": w_b, "t": t}
 
 

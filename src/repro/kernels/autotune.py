@@ -16,8 +16,9 @@ Shapes are bucketed to the next power of two per dimension so one search
 covers the whole jit-retrace neighbourhood; the backend is part of the key so
 CPU/interpret timings never masquerade as TPU tunings.
 
-Cache file: ``$REPRO_AUTOTUNE_CACHE`` if set, else
-``~/.cache/repro/autotune.json``.  Format documented in DESIGN.md Sec. 9.
+Cache file: ``$REPRO_AUTOTUNE_CACHE`` if set, else ``.autotune/autotune.json``
+at the root of the checkout (gitignored, so a fresh checkout serves the
+kernel defaults).  Format documented in DESIGN.md Sec. 9.
 
 Search-on-miss is opt-in (``REPRO_AUTOTUNE=1`` or ``autotune=True`` on the
 ``tune_*`` wrappers): a silent multi-second search in the middle of a serving
@@ -38,6 +39,7 @@ from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.utils import REPO_ROOT
 from repro.utils import next_pow2 as _next_pow2
 
 CACHE_SCHEMA_VERSION = 1
@@ -64,8 +66,7 @@ def default_cache_path() -> str:
     env = os.environ.get("REPRO_AUTOTUNE_CACHE", "")
     if env:
         return env
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                        "autotune.json")
+    return str(REPO_ROOT / ".autotune" / "autotune.json")
 
 
 def shape_bucket(dims: Sequence[int]) -> Tuple[int, ...]:
@@ -244,39 +245,43 @@ def _dtype_bytes(dtype) -> int:
 
 
 def candidates_kan_fused(B: int, n_in: int, n_out: int, nbk: int,
-                         dtype) -> List[Dict[str, int]]:
-    """(bm, bi, bn) grid for the fused KAN kernel (v2 footprint model)."""
-    eb = _dtype_bytes(dtype)
+                         dtype, order: int = 3) -> List[Dict[str, int]]:
+    """(bm, bi, bn) grid for the fused v2 KAN kernel: only tiles the kernel
+    runs as given -- fitted to the shape (``fit_blocks``' lane/sublane
+    rules) and within its scoped-VMEM limit under the lane-padded
+    ``vmem_bytes`` model."""
+    from repro.kernels.kan_fused.kan_fused import VMEM_LIMIT, vmem_bytes
+    from repro.kernels.tiling import LANES, fit_block, fit_rows
+
     out: List[Dict[str, int]] = []
-    for bm in (64, 128, 256, 512):
-        for bi in (8, 16, 32, 64, 128):
-            for bn in (64, 128, 256, 512):
-                if bm > max(8, _next_pow2(B)) or bi > _next_pow2(n_in) \
-                        or bn > _next_pow2(n_out):
-                    continue
-                kc = bi * (nbk + 1)
-                # x + fused activation tile + fused weight tile + f32 acc
-                vmem = (bm * bi * eb + bm * kc * eb + kc * bn * eb
-                        + bm * bn * 4)
-                if vmem <= VMEM_BUDGET:
+    for bm in sorted({fit_rows(b, B, dtype) for b in (8, 32, 128, 512)}):
+        for bi in sorted({fit_block(b, n_in, LANES) for b in (128, 256)}):
+            for bn in sorted({fit_block(b, n_out, LANES)
+                              for b in (128, 256, 512)}):
+                if vmem_bytes(bm, bi, bn, nbk, dtype, order) <= VMEM_LIMIT:
                     out.append({"bm": bm, "bi": bi, "bn": bn})
-    return out or [{"bm": 64, "bi": 8, "bn": 64}]
+    return out
 
 
 def candidates_pattern_matmul(M: int, K: int, N: int,
                               dtype) -> List[Dict[str, int]]:
-    eb = _dtype_bytes(dtype)
+    """(bm, bk, bn) grid for the compact matmul, fitted to the shape by the
+    same lane/sublane rules the kernel applies, within ``VMEM_BUDGET``
+    counting double-buffered lane-padded blocks and the f32 accumulator."""
+    from repro.kernels.tiling import LANES, fit_block, fit_rows, padded_bytes
+
     out: List[Dict[str, int]] = []
-    for bm in (64, 128, 256, 512):
-        for bk in (128, 256, 512, 1024):
-            for bn in (64, 128, 256, 512):
-                if bm > max(8, _next_pow2(M)) or bk > _next_pow2(K) \
-                        or bn > _next_pow2(N):
-                    continue
-                vmem = bm * bk * eb + bk * bn * eb + bm * bn * 4
+    for bm in sorted({fit_rows(b, M, dtype) for b in (8, 32, 128, 512)}):
+        for bk in sorted({fit_block(b, K, LANES) for b in (128, 512, 1024)}):
+            for bn in sorted({fit_block(b, N, LANES)
+                              for b in (128, 256, 512)}):
+                vmem = (2 * (padded_bytes(bm, bk, dtype)
+                             + padded_bytes(bk, bn, dtype)
+                             + padded_bytes(bm, bn, jnp.float32))
+                        + padded_bytes(bm, bn, jnp.float32))
                 if vmem <= VMEM_BUDGET:
                     out.append({"bm": bm, "bk": bk, "bn": bn})
-    return out or [{"bm": 64, "bk": 128, "bn": 64}]
+    return out
 
 
 def candidates_spline_basis(n: int, n_bases: int, dtype) -> List[Dict[str, int]]:
@@ -306,7 +311,7 @@ def tune_kan_fused(x, w_b, t_flat, spec, kb=None, *, version: int = 2,
     n_out = w_b.shape[1]
     kb = tuple(range(spec.n_bases)) if kb is None else tuple(kb)
     nbk = len(kb)
-    cands = candidates_kan_fused(B, n_in, n_out, nbk, x.dtype)
+    cands = candidates_kan_fused(B, n_in, n_out, nbk, x.dtype, spec.order)
     if version == 2:
         wt = fuse_wt(w_b, t_flat, nbk)
         run = lambda bm, bi, bn: kan_fused_pallas_v2(

@@ -33,9 +33,17 @@ Two kernel generations are kept:
   read-modify-writes per step; VPU work is unchanged.
 
 TSE scatter: both kernels receive the kept-basis indices as an int32 *input
-array* ``kb_arr`` (Pallas forbids captured constant arrays) and scatter with
+array* (Pallas forbids captured constant arrays) and scatter with
 ``delta = kb - cell`` plus exactly K+1 where-selects -- O(K+1) independent of
 nbk, replacing the old Python-unrolled O(nbk*(K+1)) select chain.
+
+v2 builds its fused tile in 2-D only, the layout Mosaic lowers: the wrapper
+repeats each input feature nbk+1 times along the lanes (``x_rep``, column
+``c`` holds feature ``c // (nbk+1)``), and a (1, bi*(nbk+1)) int32 row
+``kb_cols`` names each column's slot: -1 for the silu slot, the kept basis
+index for a spline slot.  The SPU/TSE arithmetic then runs per column on
+(bm, bi*(nbk+1)) values, element for element the same as the per-feature
+form, and no (bm, bi, nbk) value or lane-splitting reshape is ever formed.
 
 Weight layouts: v1 takes ``t_flat`` (n_in * nbk, n_out), rows grouped by
 input feature, basis-index fastest.  v2 takes the fused ``wt``
@@ -45,7 +53,10 @@ exist; kb = range(G+K) when no pattern mask is set.
 
 Block sizes (bm, bi, bn) are tunable per shape/dtype/backend through
 ``repro.kernels.autotune`` (see DESIGN.md Sec. 9); the defaults below are
-the untuned fallback.
+the untuned fallback.  Every kernel call first fits its blocks to the shape
+(``fit_blocks``): bi and bn 128-lane aligned or the whole axis, bm a
+multiple of the dtype's sublane rows, and bm halved until the v2 tile fits
+``VMEM_LIMIT`` under ``vmem_bytes``.
 """
 from __future__ import annotations
 
@@ -58,10 +69,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.splines import INV_LUT, SplineSpec
+from repro.kernels.tiling import (
+    LANES,
+    fit_block,
+    fit_rows,
+    mxu_precision,
+    padded_bytes,
+    sublanes,
+)
 
 DEFAULT_BM = 128
-DEFAULT_BI = 64
+DEFAULT_BI = 128
 DEFAULT_BN = 128
+
+# Scoped VMEM a kernel may use: Mosaic's default limit on TPU v5e.
+VMEM_LIMIT = 16 * 1024 * 1024
 
 # MXU contractions issued per (bm, bn, i) grid step -- the quantity v2
 # halves.  kernel_bench verifies these against the traced jaxpr.
@@ -117,6 +139,19 @@ def _tse_scatter(vals, cell_i, kb_row, nbk: int):
     return act
 
 
+def _fused_tile(x_rep, kb_cols, spec: SplineSpec):
+    """SIMD + SPU + TSE on the repeated input: the (bm, bi*(nbk+1)) fused
+    ``[silu | bases]`` activation tile, per column the values
+    ``_tse_scatter`` would place there, with the silu slot (``kb_cols`` <
+    0, which no ``delta`` in 0..K can match) selecting silu(x)."""
+    s, vals, cell_i = _spu_tile(x_rep, spec)
+    delta = kb_cols - cell_i
+    act = jnp.zeros_like(s)
+    for j in range(len(vals)):
+        act = act + jnp.where(delta == j, vals[j], 0.0)
+    return jnp.where(kb_cols < 0, s, act)
+
+
 def _kan_kernel(
     x_ref, kb_ref, wb_ref, t_ref, o_ref, acc_ref,
     *, spec: SplineSpec, nbk: int, i_steps: int,
@@ -147,8 +182,7 @@ def _kan_kernel(
 
 
 def _kan_kernel_v2(
-    x_ref, kb_ref, wt_ref, o_ref, acc_ref,
-    *, spec: SplineSpec, nbk: int, i_steps: int,
+    x_ref, kb_ref, wt_ref, o_ref, acc_ref, *, spec: SplineSpec, i_steps: int,
 ):
     """v2: ONE MXU dispatch per step on the fused [silu | bases] tile."""
     i = pl.program_id(2)
@@ -157,19 +191,13 @@ def _kan_kernel_v2(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]                       # (bm, bi)
-    s, vals, cell_i = _spu_tile(x, spec)
-    act = _tse_scatter(vals, cell_i, kb_ref[...], nbk)    # (bm, bi, nbk)
-
     # --- PE array: single fused contraction.  Per feature p the activation
     # columns are [silu(x_p), B_{kb0}(x_p), ..., B_{kb(nbk-1)}(x_p)],
     # matching fuse_wt's row interleave [w_b[p] ; t[p, kb]].
-    bm, bi = x.shape
-    fused = jnp.concatenate([s[..., None], act], axis=-1)  # (bm, bi, nbk+1)
-    acc_ref[...] += jnp.dot(
-        fused.reshape(bm, bi * (nbk + 1)), wt_ref[...],
-        preferred_element_type=jnp.float32,
-    )
+    fused = _fused_tile(x_ref[...], kb_ref[...], spec)    # (bm, bi*(nbk+1))
+    acc_ref[...] += jnp.dot(fused, wt_ref[...],
+                            precision=mxu_precision(fused.dtype),
+                            preferred_element_type=jnp.float32)
 
     @pl.when(i == i_steps - 1)
     def _epilogue():
@@ -177,17 +205,18 @@ def _kan_kernel_v2(
 
 
 def _kan_kernel_v2_q8(
-    x_ref, kb_ref, wt_ref, ss_ref, o_ref, acc_ref,
-    *, spec: SplineSpec, nbk: int, i_steps: int, x_scale: float,
+    x_ref, kb_ref, wt_ref, rs_ref, o_ref, acc_ref,
+    *, spec: SplineSpec, i_steps: int, x_scale: float,
 ):
     """v2 int8 variant: dequantize-on-load, f32 SPU/accumulate, f32 out.
 
     The activation tile is real-valued (silu + spline bases of the
     dequantized input), so unlike the pattern-matmul q8 kernel the MXU
     contraction here cannot stay in integer codes -- both operands widen
-    on load.  ``x_scale`` is the layer's static input scale; ``ss_ref``
-    is the (1, nbk+1) per-slot weight scale vector matching fuse_wt's
-    row interleave ([w_b ; t[kb]] per input feature).
+    on load.  ``x_scale`` is the layer's static input scale; ``rs_ref``
+    is the (bi*(nbk+1), 1) column of per-row weight scales: fuse_wt's row
+    interleave ([w_b ; t[kb]] per input feature) gives each row slot its
+    own symmetric scale.
     """
     i = pl.program_id(2)
 
@@ -196,28 +225,50 @@ def _kan_kernel_v2_q8(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.float32) * x_scale          # dequant on load
-    s, vals, cell_i = _spu_tile(x, spec)
-    act = _tse_scatter(vals, cell_i, kb_ref[...], nbk)    # (bm, bi, nbk)
-
-    bm, bi = x.shape
-    # Dequantize the fused weight tile per row slot: rows of one input
-    # feature are [w_b ; t[kb0] ; ...], each with its own symmetric scale.
-    wt = wt_ref[...].astype(jnp.float32).reshape(bi, nbk + 1, -1)
-    wt = (wt * ss_ref[...].reshape(1, nbk + 1, 1)).reshape(
-        bi * (nbk + 1), -1)
-    fused = jnp.concatenate([s[..., None], act], axis=-1)  # (bm, bi, nbk+1)
-    acc_ref[...] += jnp.dot(
-        fused.reshape(bm, bi * (nbk + 1)), wt,
-        preferred_element_type=jnp.float32,
-    )
+    fused = _fused_tile(x, kb_ref[...], spec)
+    wt = wt_ref[...].astype(jnp.float32) * rs_ref[...]
+    acc_ref[...] += jnp.dot(fused, wt, precision=mxu_precision(wt.dtype),
+                            preferred_element_type=jnp.float32)
 
     @pl.when(i == i_steps - 1)
     def _epilogue():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _clamp_blocks(B, n_in, n_out, bm, bi, bn):
-    return min(bm, max(8, B)), min(bi, n_in), min(bn, n_out)
+def vmem_bytes(bm: int, bi: int, bn: int, nbk: int, dtype,
+               order: int = 3) -> int:
+    """Scoped VMEM one v2 grid step needs, every value counted in its
+    native (sublane, 128) tiles: double-buffered input, kb_cols, weight
+    and output blocks (plus the q8 row-scale column), the f32
+    accumulator, and the body's f32 temporaries."""
+    kc = bi * (nbk + 1)
+    q8 = jnp.dtype(dtype) == jnp.int8
+    buffers = (padded_bytes(bm, kc, dtype) + padded_bytes(1, kc, jnp.int32)
+               + padded_bytes(kc, bn, dtype)
+               + padded_bytes(bm, bn, jnp.float32)
+               + (padded_bytes(kc, 1, jnp.float32) if q8 else 0))
+    # f32 (bm, kc) values the body may keep live: the K+1 basis planes, K
+    # left and K right stage-buffer terms, silu / cell / delta / act / the
+    # fused tile
+    body_tiles = 3 * order + 6
+    return (2 * buffers + padded_bytes(bm, bn, jnp.float32)
+            + body_tiles * padded_bytes(bm, kc, jnp.float32))
+
+
+def fit_blocks(B: int, n_in: int, n_out: int, nbk: int, dtype,
+               bm: int, bi: int, bn: int,
+               order: int = 3) -> Tuple[int, int, int]:
+    """Blocks Mosaic accepts for this shape: bi/bn 128-lane aligned or the
+    whole axis, bm a multiple of the sublane rows, halved while the step
+    exceeds ``VMEM_LIMIT``."""
+    bi = fit_block(bi, n_in, LANES)
+    bn = fit_block(bn, n_out, LANES)
+    bm = fit_rows(bm, B, dtype)
+    sub = sublanes(dtype)
+    while bm > sub and vmem_bytes(bm, bi, bn, nbk, dtype,
+                                  order) > VMEM_LIMIT:
+        bm = fit_rows(bm // 2, B, dtype)
+    return bm, bi, bn
 
 
 @functools.partial(
@@ -249,7 +300,8 @@ def kan_fused_pallas(
     nbk = len(kb)
     assert t_flat.shape == (n_in * nbk, n_out), (t_flat.shape, n_in, nbk)
 
-    bm, bi, bn = _clamp_blocks(B, n_in, n_out, bm, bi, bn)
+    bm, bi, bn = fit_blocks(B, n_in, n_out, nbk, x.dtype, bm, bi, bn,
+                            order=spec.order)
     pb, pi, pn = -B % bm, -n_in % bi, -n_out % bn
     # Pad inputs with x0 (in-range) and weights with zeros: contributes
     # nothing because the padded w_b/t rows are zero.
@@ -277,6 +329,54 @@ def kan_fused_pallas(
     return out[:B, :n_out]
 
 
+def _v2_call(body, x, wt, extra, spec: SplineSpec, kb: Tuple[int, ...],
+             blocks: Tuple[int, int, int], pad_value: float, out_dtype,
+             interpret: bool) -> jax.Array:
+    """Shared v2 wrapper: fit the blocks, pad, repeat every input feature
+    across its nbk+1 fused columns, and run ``body`` over the grid.
+
+    ``extra`` is the q8 (nbk+1,) slot-scale vector, expanded here into the
+    kernel's (bi*(nbk+1), 1) row-scale column, or None for f32/bf16.
+    """
+    B, n_in = x.shape
+    n_out = wt.shape[1]
+    nbk = len(kb)
+    assert wt.shape == (n_in * (nbk + 1), n_out), (wt.shape, n_in, nbk)
+    bm, bi, bn = fit_blocks(B, n_in, n_out, nbk, x.dtype, *blocks,
+                            order=spec.order)
+    kc = bi * (nbk + 1)
+    pb, pi, pn = -B % bm, -n_in % bi, -n_out % bn
+    # Padded features read pad_value (in-range) against zero weight rows,
+    # so they contribute nothing.
+    xp = jnp.pad(x, ((0, pb), (0, pi)), constant_values=pad_value)
+    x_rep = jnp.repeat(xp, nbk + 1, axis=1)       # (Bp, Ip*(nbk+1))
+    wtp = jnp.pad(wt, ((0, pi * (nbk + 1)), (0, pn)))
+    slots = jnp.asarray((-1,) + kb, jnp.int32)
+    kb_cols = jnp.tile(slots, bi)[None, :]        # (1, kc), same every i
+    Bp, Ip, Np = B + pb, n_in + pi, n_out + pn
+    i_steps = Ip // bi
+    in_specs = [
+        pl.BlockSpec((bm, kc), lambda b, n, i: (b, i)),
+        pl.BlockSpec((1, kc), lambda b, n, i: (0, 0)),
+        pl.BlockSpec((kc, bn), lambda b, n, i: (i, n)),
+    ]
+    args = [x_rep, kb_cols, wtp]
+    if extra is not None:
+        rs = jnp.tile(extra.astype(jnp.float32).reshape(nbk + 1), bi)
+        in_specs.append(pl.BlockSpec((kc, 1), lambda b, n, i: (0, 0)))
+        args.append(rs[:, None])
+    out = pl.pallas_call(
+        functools.partial(body, spec=spec, i_steps=i_steps),
+        grid=(Bp // bm, Np // bn, i_steps),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bm, bn), lambda b, n, i: (b, n)),
+        out_shape=jax.ShapeDtypeStruct((Bp, Np), out_dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        interpret=interpret,
+    )(*args)
+    return out[:B, :n_out]
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("spec", "kb", "bm", "bi", "bn", "interpret", "out_dtype"),
@@ -298,36 +398,9 @@ def kan_fused_pallas_v2(
     ``out_dtype`` (default: x.dtype) lets bf16 inputs emit the f32
     accumulator directly (mixed-precision serving / oracle comparison).
     """
-    out_dtype = out_dtype or x.dtype
-    B, n_in = x.shape
-    n_out = wt.shape[1]
     kb = tuple(range(spec.n_bases)) if kb is None else tuple(kb)
-    nbk = len(kb)
-    assert wt.shape == (n_in * (nbk + 1), n_out), (wt.shape, n_in, nbk)
-
-    bm, bi, bn = _clamp_blocks(B, n_in, n_out, bm, bi, bn)
-    pb, pi, pn = -B % bm, -n_in % bi, -n_out % bn
-    xp = jnp.pad(x, ((0, pb), (0, pi)), constant_values=spec.x0)
-    wtp = jnp.pad(wt, ((0, pi * (nbk + 1)), (0, pn)))
-    kb_arr = jnp.asarray(kb, jnp.int32)[None, :]          # (1, nbk) input
-    Bp, Ip, Np = B + pb, n_in + pi, n_out + pn
-    i_steps = Ip // bi
-
-    out = pl.pallas_call(
-        functools.partial(_kan_kernel_v2, spec=spec, nbk=nbk,
-                          i_steps=i_steps),
-        grid=(Bp // bm, Np // bn, i_steps),
-        in_specs=[
-            pl.BlockSpec((bm, bi), lambda b, n, i: (b, i)),
-            pl.BlockSpec((1, nbk), lambda b, n, i: (0, 0)),
-            pl.BlockSpec((bi * (nbk + 1), bn), lambda b, n, i: (i, n)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda b, n, i: (b, n)),
-        out_shape=jax.ShapeDtypeStruct((Bp, Np), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(xp, kb_arr, wtp)
-    return out[:B, :n_out]
+    return _v2_call(_kan_kernel_v2, x, wt, None, spec, kb, (bm, bi, bn),
+                    spec.x0, out_dtype or x.dtype, interpret)
 
 
 @functools.partial(
@@ -354,38 +427,10 @@ def kan_fused_pallas_v2_q8(
     The int8 weight stream is what the DMA-byte saving in
     ``core/engine.serving_report`` models; the arithmetic contract is
     core/quant's (dequantize on load, accumulate f32, emit f32 -- the
-    caller requantizes).
+    caller requantizes).  Int8 zero pads dequantize to 0.0, which the SPU
+    clips into the spline domain.
     """
-    B, n_in = x_q.shape
-    n_out = wt_q.shape[1]
     kb = tuple(range(spec.n_bases)) if kb is None else tuple(kb)
-    nbk = len(kb)
-    assert wt_q.shape == (n_in * (nbk + 1), n_out), (wt_q.shape, n_in, nbk)
-
-    bm, bi, bn = _clamp_blocks(B, n_in, n_out, bm, bi, bn)
-    pb, pi, pn = -B % bm, -n_in % bi, -n_out % bn
-    # Int8 zero pads dequantize to 0.0; _spu_tile clips into the spline
-    # domain and the padded (zero) weight rows null the contribution.
-    xp = jnp.pad(x_q, ((0, pb), (0, pi)))
-    wtp = jnp.pad(wt_q, ((0, pi * (nbk + 1)), (0, pn)))
-    kb_arr = jnp.asarray(kb, jnp.int32)[None, :]          # (1, nbk) input
-    ss = slot_scales.astype(jnp.float32).reshape(1, nbk + 1)
-    Bp, Ip, Np = B + pb, n_in + pi, n_out + pn
-    i_steps = Ip // bi
-
-    out = pl.pallas_call(
-        functools.partial(_kan_kernel_v2_q8, spec=spec, nbk=nbk,
-                          i_steps=i_steps, x_scale=float(x_scale)),
-        grid=(Bp // bm, Np // bn, i_steps),
-        in_specs=[
-            pl.BlockSpec((bm, bi), lambda b, n, i: (b, i)),
-            pl.BlockSpec((1, nbk), lambda b, n, i: (0, 0)),
-            pl.BlockSpec((bi * (nbk + 1), bn), lambda b, n, i: (i, n)),
-            pl.BlockSpec((1, nbk + 1), lambda b, n, i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda b, n, i: (b, n)),
-        out_shape=jax.ShapeDtypeStruct((Bp, Np), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(xp, kb_arr, wtp, ss)
-    return out[:B, :n_out]
+    body = functools.partial(_kan_kernel_v2_q8, x_scale=float(x_scale))
+    return _v2_call(body, x_q, wt_q, slot_scales, spec, kb, (bm, bi, bn),
+                    0, out_dtype, interpret)

@@ -11,7 +11,8 @@ against at 1e-4).
 
 Block sizes for the Pallas path resolve, in order: explicit ``blocks``
 argument > autotune cache hit for (shape bucket, dtype, backend) > module
-defaults.  See ``repro.kernels.autotune``.
+defaults (see ``repro.kernels.autotune``); the kernel then fits them to
+the call's shape (``kan_fused.fit_blocks``).
 """
 from __future__ import annotations
 
@@ -23,6 +24,7 @@ import jax.numpy as jnp
 
 from repro.core.splines import SplineSpec, bases_local, scatter_kept, silu
 from repro.kernels import autotune
+from repro.kernels.tiling import gemm_rows
 from repro.kernels.kan_fused.kan_fused import (
     DEFAULT_BI,
     DEFAULT_BM,
@@ -94,8 +96,9 @@ def _kan_linear_jnp(
     x: jax.Array, w_b: jax.Array, t_flat: jax.Array, spec: SplineSpec,
     kb: Tuple[int, ...], version: int, out_dtype=None,
 ) -> jax.Array:
-    n_in = x.shape[-1]
+    n_rows, n_in = x.shape
     nbk = len(kb)
+    x = gemm_rows(x)
     # Stage 1: only K+1 basis values are computed (VPU-op saving); stage 2:
     # broadcast iota-comparison scatter straight into the kept-basis columns
     # (K+1 selects, independent of nbk) -- same TSE form as the kernels.
@@ -118,7 +121,7 @@ def _kan_linear_jnp(
             act.reshape(-1, n_in * nbk), t_flat,
             preferred_element_type=jnp.float32,
         )
-    return y.astype(out_dtype or x.dtype)
+    return y[:n_rows].astype(out_dtype or x.dtype)
 
 
 @functools.partial(

@@ -22,6 +22,7 @@ from repro.kernels.pattern_matmul.pattern_matmul import (
     matmul_q8_pallas,
 )
 from repro.kernels.epilogue import bias_act, scale_bias_act
+from repro.kernels.tiling import gemm_rows
 
 
 def _on_tpu() -> bool:
@@ -80,7 +81,8 @@ def pattern_linear(
                                   interpret=(impl == "pallas_interpret"),
                                   **bk)
     elif impl == "jnp":
-        acc = jnp.dot(xf, w, preferred_element_type=jnp.float32)
+        acc = jnp.dot(gemm_rows(xf), w,
+                      preferred_element_type=jnp.float32)[:xf.shape[0]]
         # the SAME epilogue the Pallas kernel fuses (VL002 contract)
         y = bias_act(acc, bias, act, x.dtype)
     else:

@@ -8,9 +8,11 @@ scratch and a fused bias+activation epilogue -- the MXU analogue of the PE
 array receiving a zero-free dense stream from the TSE (paper Fig. 5b).
 
 Tiling: grid (M/bm, N/bn, Kc/bk), k innermost so the (bm,bn) accumulator
-lives across k-steps.  Blocks are MXU-aligned (multiples of 128 on real
-shapes); defaults keep x-block + w-block + acc comfortably inside one core's
-VMEM (bm*bk + bk*bn at 2B plus bm*bn at 4B ~= 196 KiB at 128/512/128).
+lives across k-steps.  Every call fits its blocks to the shape
+(``fit_blocks``): bk and bn 128-lane aligned or the whole axis, bm a
+multiple of the dtype's sublane rows; defaults keep x-block + w-block + acc
+comfortably inside one core's VMEM (bm*bk + bk*bn at 2B plus bm*bn at 4B
+~= 196 KiB at 128/512/128).
 """
 from __future__ import annotations
 
@@ -23,10 +25,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.epilogue import bias_act
+from repro.kernels.tiling import LANES, fit_block, fit_rows, mxu_precision
 
 DEFAULT_BM = 128
 DEFAULT_BK = 512
 DEFAULT_BN = 128
+
+
+def fit_blocks(M: int, Kc: int, N: int, dtype, bm: int, bk: int,
+               bn: int):
+    """Blocks Mosaic accepts for an (M, Kc) x (Kc, N) contraction."""
+    return (fit_rows(bm, M, dtype), fit_block(bk, Kc, LANES),
+            fit_block(bn, N, LANES))
 
 
 def _mm_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, k_steps: int, act):
@@ -37,7 +47,8 @@ def _mm_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, k_steps: int, act):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        x_ref[...], w_ref[...], preferred_element_type=jnp.float32
+        x_ref[...], w_ref[...], precision=mxu_precision(x_ref.dtype),
+        preferred_element_type=jnp.float32,
     )
 
     @pl.when(k == k_steps - 1)
@@ -90,6 +101,7 @@ def matmul_q8_pallas(
     Kc2, N = w_q.shape
     assert Kc == Kc2, (Kc, Kc2)
 
+    bm, bk, bn = fit_blocks(M, Kc, N, x_q.dtype, bm, bk, bn)
     # Int8 zero pads are matmul-neutral just like f32 zeros.
     pm, pk, pn = -M % bm, -Kc % bk, -N % bn
     xp = jnp.pad(x_q, ((0, pm), (0, pk)))
@@ -135,6 +147,7 @@ def matmul_compact_pallas(
     if bias is None:
         bias = jnp.zeros((N,), out_dtype)
 
+    bm, bk, bn = fit_blocks(M, Kc, N, x_c.dtype, bm, bk, bn)
     # Pad every dim up to its block size (zero pads are matmul-neutral).
     pm, pk, pn = -M % bm, -Kc % bk, -N % bn
     xp = jnp.pad(x_c, ((0, pm), (0, pk)))

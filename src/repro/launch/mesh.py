@@ -11,8 +11,6 @@ from typing import Tuple
 
 import jax
 
-from repro import jax_compat  # noqa: F401  (installs AxisType/make_mesh shims)
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)

@@ -138,7 +138,7 @@ def _make_vikin_backend(args, model):
         from repro.core.calibrate import calibrate_scales
         rng = np.random.default_rng(0)
         calib_x = rng.random((256, model.sizes[0])).astype(np.float32)
-        scales = calibrate_scales(params, model, calib_x, impl="jnp")
+        scales = calibrate_scales(params, model, calib_x, impl=args.impl)
         print(f"no checkpoint: calibrated int8 scales from a synthetic "
               f"batch (x={scales.summary()['x']})")
     if args.devices > 1:
@@ -180,9 +180,10 @@ def _make_vikin_backend(args, model):
     return backend
 
 
-def _serve_vikin(args, models):
-    import numpy as np
-
+def build_vikin_engine(args, models):
+    """The Engine ``--arch vikin-*`` serves from: one backend per model
+    (a MultiWorkloadBackend over several), built as the flags say.
+    Returns (engine, models as served, multi-workload?)."""
     from repro.runtime.backends import MultiWorkloadBackend
     from repro.runtime.server import Engine
 
@@ -220,6 +221,13 @@ def _serve_vikin(args, models):
               f"max_queue {eng.max_queue} per workload"
               + (", expired queued requests dropped" if eng.drop_expired
                  else ""))
+    return eng, models, multi
+
+
+def _serve_vikin(args, models):
+    import numpy as np
+
+    eng, models, multi = build_vikin_engine(args, models)
     if args.trace:
         return _replay_trace(args, eng)
 
@@ -300,9 +308,10 @@ def _replay_trace(args, eng):
               "(max_ticks or stalled admission)")
 
 
-def _serve_transformer(args, cfg):
+def build_transformer_server(args, cfg):
+    """The Server a transformer ``--arch`` serves from, built as the flags
+    say (random init from key 0 unless ``--ckpt-dir`` restores params)."""
     import jax
-    import numpy as np
 
     from repro.checkpoint import latest_step, restore_checkpoint
     from repro.models import transformer as T
@@ -334,6 +343,14 @@ def _serve_transformer(args, cfg):
         print(f"mode plan: {plan['segments']} "
               f"({plan['n_switches']} switches, "
               f"{plan['reconfig_cycles']} reconfig cycles/instance)")
+    return srv
+
+
+def _serve_transformer(args, cfg):
+    import numpy as np
+
+    srv = build_transformer_server(args, cfg)
+    kanffn = cfg.ffn_kinds is not None
     rng = np.random.default_rng(0)
     for i in range(args.requests):
         n = int(rng.integers(3, 16))
@@ -352,7 +369,7 @@ def _serve_transformer(args, cfg):
               f"({s['reconfig_cycles']:.0f} reconfig cycles)")
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True,
                     help="one arch id, or a comma list of vikin-* archs "
@@ -411,11 +428,15 @@ def main():
     ap.add_argument("--drop-expired", action="store_true",
                     help="shed queued requests whose deadline already "
                          "passed instead of serving them dead")
-    args = ap.parse_args()
+    return ap
 
+
+def resolve_archs(arch: str):
+    """``--arch`` -> [(family, config), ...], with the launcher's rules on
+    which families may be served together."""
     from repro.configs.registry import get_serving_config
 
-    names = [a.strip() for a in args.arch.split(",") if a.strip()]
+    names = [a.strip() for a in arch.split(",") if a.strip()]
     if not names:
         raise SystemExit("--arch got no arch ids; pass one id or a comma "
                          "list like vikin-kan2,vikin-mlp3")
@@ -429,6 +450,17 @@ def main():
             f"multi-workload serving (--arch a,b,c) is vikin-only "
             f"(runtime/scheduler.py); got families {sorted(families)}. "
             f"Serve one transformer arch at a time")
+    return resolved
+
+
+def main():
+    args = build_parser().parse_args()
+
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
+    resolved = resolve_archs(args.arch)
+    families = {fam for fam, _ in resolved}
     if families == {"vikin"}:
         _serve_vikin(args, [cfg for _, cfg in resolved])
     else:
@@ -457,7 +489,8 @@ def main():
             if cfg.ffn_kinds is None:
                 raise SystemExit(
                     f"--precision is vikin/kan-ffn-only; plain arch "
-                    f"{args.arch!r} would silently serve f32 anyway")
+                    f"{args.arch!r} serves its configured dtype "
+                    f"({cfg.dtype})")
             if args.precision == "int8":
                 raise SystemExit(
                     "--precision int8 is vikin-only (core/quant path); "

@@ -28,8 +28,6 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import jax_compat  # noqa: F401  (installs AxisType/make_mesh shims)
-
 PyTree = Any
 
 # (keypath regex, PartitionSpec builder) -- first match wins.

@@ -138,6 +138,9 @@ def main():
     args = ap.parse_args()
 
     from repro.configs.vikin_models import VIKIN_ARCHS
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.arch in VIKIN_ARCHS:
         if args.lr is None:

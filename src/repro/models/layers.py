@@ -124,11 +124,8 @@ def shard_hint(x: jax.Array, *axes) -> jax.Array:
     dropped, so model code can state its intent ('experts over model,
     capacity over data') and still run on a 1-device CPU mesh.
     """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        names = set(mesh.axis_names) if mesh is not None else set()
-    except Exception:
-        names = set()
+    mesh = jax.sharding.get_abstract_mesh()
+    names = set(mesh.axis_names)
     if not names:
         return x
 

@@ -169,13 +169,8 @@ def _moe_local(xt, router_k, gate_w, up_w, down_w, cfg: MoEConfig,
 
 
 def _ambient_mesh_axes():
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and mesh.axis_names:
-            return dict(zip(mesh.axis_names, mesh.axis_sizes))
-    except Exception:
-        pass
-    return {}
+    mesh = jax.sharding.get_abstract_mesh()
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def moe_apply(params: Dict, x: jax.Array, cfg: MoEConfig,

@@ -235,13 +235,18 @@ class TransformerBackend(ModelBackend):
             cfg = dataclasses.replace(cfg, ffn_masks=tuple(masks))
         if impl is not None and cfg.ffn_kinds is not None:
             cfg = dataclasses.replace(cfg, ffn_impl=impl)
-        if precision == "bf16":
+        # bf16 casts any arch; f32 casts only kan-ffn archs (a bf16-configured
+        # kan-ffn arch served at f32 runs f32), while plain archs served at
+        # the default keep their configured dtype
+        kanffn = cfg.ffn_kinds is not None
+        dtype = ("bfloat16" if precision == "bf16"
+                 else "float32" if kanffn else cfg.dtype)
+        if cfg.dtype != dtype:
             import jax.numpy as jnp
 
-            if cfg.dtype != "bfloat16":
-                cfg = dataclasses.replace(cfg, dtype="bfloat16")
+            cfg = dataclasses.replace(cfg, dtype=dtype)
             params = jax.tree.map(
-                lambda a: (a.astype(jnp.bfloat16)
+                lambda a: (a.astype(cfg.param_dtype)
                            if jnp.issubdtype(a.dtype, jnp.floating) else a),
                 params)
         self.cfg, self.params = cfg, params
@@ -278,19 +283,24 @@ class TransformerBackend(ModelBackend):
             self._prefill_cache[length] = self._jax.jit(fn)
         return self._prefill_cache[length]
 
+    def prefill_logits(self, tokens: np.ndarray) -> Tuple[Any, Any]:
+        """(last-position logits, batch caches) of the served prefill for
+        a (batch, length) int32 token array."""
+        import jax.numpy as jnp
+
+        return self._prefill_fn(tokens.shape[1])(
+            self.params, jnp.asarray(tokens, jnp.int32))
+
     def prefill(self, caches: Any, slot: int, req: Request) -> Any:
         """Prefill one request and splice its (batch=1) cache into lane
         ``slot`` of the server's (batch=n_slots) caches."""
-        import jax.numpy as jnp
-
         jax, T = self._jax, self._T
         tokens = np.asarray(req.prompt, np.int32)[None, :]
         if self.layers is not None:
             # each prefilled prompt token is one model instance the cycle
             # model must charge on the NEXT tick's report
             self._pending_prefill += tokens.shape[1]
-        logits, cache = self._prefill_fn(tokens.shape[1])(
-            self.params, jnp.asarray(tokens))
+        logits, cache = self.prefill_logits(tokens)
         next_tok = int(jax.device_get(T.greedy_token(logits))[0, 0])
         req.generated.append(next_tok)
 

@@ -46,7 +46,6 @@ from typing import Any, List, Optional, Sequence, Tuple
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import jax_compat
 from repro.core.engine import VikinArray, VikinHW
 from repro.core.modes import parse_mode
 from repro.launch.mesh import require_devices, serving_mesh
@@ -98,12 +97,12 @@ class ShardedVikinBackend(VikinBackend):
         # (tiny, KB-scale) stack; requests shard, weights don't.
         self.params = jax.device_put(
             self.params, NamedSharding(self.mesh, P()))
-        fwd = jax_compat.shard_map(
+        fwd = jax.shard_map(
             self.forward_fn(),
             mesh=self.mesh,
             in_specs=(P(), P("data", None)),
             out_specs=P("data", None),
-            check_rep=False,
+            check_vma=False,
         )
         self._fwd = jax.jit(fwd)
 

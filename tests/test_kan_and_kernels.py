@@ -47,10 +47,12 @@ def _kan_setup(n_in=9, n_out=13, g=4, k=3, pattern=None, seed=0, dtype=jnp.float
 @pytest.mark.parametrize("g,k", [(2, 1), (4, 3), (8, 2), (16, 4)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_kan_fused_kernel_vs_ref(g, k, dtype):
-    cfg, params, x = _kan_setup(g=g, k=k, dtype=dtype)
+    # n_in 150: blocks are fitted 128-lane aligned, so two padded i-steps
+    cfg, params, x = _kan_setup(n_in=150, g=g, k=k, dtype=dtype)
     t_flat = flatten_t(params["t"])
     got = kan_fused_pallas(
-        x, params["w_b"], t_flat, cfg.spec, bm=8, bi=4, bn=8, interpret=True
+        x, params["w_b"], t_flat, cfg.spec, bm=8, bi=128, bn=128,
+        interpret=True,
     )
     want = kan_layer_ref(x, params["w_b"], params["t"], cfg.spec)
     atol = 1e-4 if dtype == jnp.float32 else 6e-2
@@ -63,10 +65,10 @@ def test_kan_fused_kernel_vs_ref(g, k, dtype):
 def test_kan_fused_kernel_pattern_sparsity(rate):
     """Compacted kernel == dense oracle with multiplicative mask."""
     pattern = sparsity_to_pattern(rate)
-    cfg, params, x = _kan_setup(g=8, k=3, pattern=pattern)
+    cfg, params, x = _kan_setup(n_in=150, g=8, k=3, pattern=pattern)
     t_flat = flatten_t(params["t"], cfg.kb)
     got = kan_fused_pallas(
-        x, params["w_b"], t_flat, cfg.spec, cfg.kb, bm=8, bi=4, bn=8,
+        x, params["w_b"], t_flat, cfg.spec, cfg.kb, bm=8, bi=128, bn=128,
         interpret=True,
     )
     want = kan_layer_ref(

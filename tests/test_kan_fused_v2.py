@@ -32,8 +32,10 @@ def _layer(n_in, n_out, pattern, dtype, seed=0, spec=SplineSpec(4, 3)):
 
 
 # Shapes chosen so B, n_in, n_out are NOT multiples of the block sizes used
-# below (bm=64, bi=24, bn=32) -> every padding branch runs.
-PAD_SHAPES = [(100, 72, 96), (37, 50, 33), (129, 30, 130)]
+# below (bm=64, bi=128, bn=128; blocks are fitted per call, so an axis the
+# block covers runs whole in one step) -> every padding branch runs, and
+# n_in > 128 splits the input-feature axis into several accumulated steps.
+PAD_SHAPES = [(100, 72, 96), (37, 50, 33), (129, 30, 130), (37, 150, 33)]
 PATTERNS = [None, (1, 0, 1, 0), (1, 0, 0, 0)]
 
 
@@ -45,7 +47,7 @@ def test_v2_f32_vs_dense_ref(shape, pattern):
     x = jax.random.normal(jax.random.key(1), (B, n_in), jnp.float32)
     wt = kan_fused_weights(params, cfg)
     got = kan_fused_pallas_v2(x, wt, cfg.spec, cfg.kb,
-                              bm=64, bi=24, bn=32, interpret=True)
+                              bm=64, bi=128, bn=128, interpret=True)
     want = kan_layer_ref(x, params["w_b"], params["t"], cfg.spec,
                          basis_mask=cfg.basis_mask)
     assert float(jnp.max(jnp.abs(got - want))) <= 1e-4
@@ -54,7 +56,8 @@ def test_v2_f32_vs_dense_ref(shape, pattern):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("pattern", PATTERNS)
 def test_v2_vs_jnp_oracle_both_dtypes(pattern, dtype):
-    B, n_in, n_out = 100, 72, 96
+    # n_in 300 -> 3 padded i-steps of 128; n_out 150 -> 2 padded n-steps
+    B, n_in, n_out = 100, 300, 150
     cfg, params = _layer(n_in, n_out, pattern, dtype)
     x = jax.random.normal(jax.random.key(2), (B, n_in), dtype)
     t_flat = flatten_t(params["t"], cfg.kb)
@@ -62,15 +65,15 @@ def test_v2_vs_jnp_oracle_both_dtypes(pattern, dtype):
     # out_dtype=f32 compares the fp32 accumulators directly: the kernel and
     # the oracle agree far below 1e-4; only the final bf16 output rounding
     # can tie-break differently (one ulp), which is not a kernel property.
-    got = kan_fused_pallas_v2(x, wt, cfg.spec, cfg.kb, bm=64, bi=24, bn=32,
-                              interpret=True, out_dtype=jnp.float32)
+    got = kan_fused_pallas_v2(x, wt, cfg.spec, cfg.kb, bm=64, bi=128,
+                              bn=128, interpret=True, out_dtype=jnp.float32)
     want = kan_linear(x, params["w_b"], t_flat, cfg.spec, cfg.kb, impl="jnp",
                       out_dtype=jnp.float32)
     err = float(jnp.max(jnp.abs(got - want)))
     assert err <= 1e-4, (pattern, dtype, err)
     # the rounded bf16 outputs agree to one output ulp
-    got_r = kan_fused_pallas_v2(x, wt, cfg.spec, cfg.kb, bm=64, bi=24,
-                                bn=32, interpret=True)
+    got_r = kan_fused_pallas_v2(x, wt, cfg.spec, cfg.kb, bm=64, bi=128,
+                                bn=128, interpret=True)
     want_r = kan_linear(x, params["w_b"], t_flat, cfg.spec, cfg.kb,
                         impl="jnp")
     ulp = 1e-4 if dtype == jnp.float32 else 2 ** -8
@@ -81,13 +84,14 @@ def test_v2_vs_jnp_oracle_both_dtypes(pattern, dtype):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_v2_bf16_padding_path(dtype):
-    """Padding path with a kb subset at reduced precision."""
-    B, n_in, n_out = 37, 50, 33
+    """Padding path with a kb subset at reduced precision: n_in 150 pads
+    to two i-steps of 128."""
+    B, n_in, n_out = 37, 150, 33
     cfg, params = _layer(n_in, n_out, (1, 1, 0, 0), dtype)
     x = jax.random.normal(jax.random.key(3), (B, n_in), dtype)
     t_flat = flatten_t(params["t"], cfg.kb)
     got = kan_linear(x, params["w_b"], t_flat, cfg.spec, cfg.kb,
-                     impl="pallas_interpret", blocks=(64, 24, 32),
+                     impl="pallas_interpret", blocks=(64, 128, 128),
                      out_dtype=jnp.float32)
     want = kan_linear(x, params["w_b"], t_flat, cfg.spec, cfg.kb, impl="jnp",
                       out_dtype=jnp.float32)
@@ -102,14 +106,15 @@ def test_v2_bf16_padding_path(dtype):
 
 
 def test_v1_v2_agree():
-    cfg, params = _layer(72, 96, (1, 0, 1, 0), jnp.float32)
-    x = jax.random.normal(jax.random.key(4), (64, 72))
+    # n_in 150 / n_out 150: two padded i- and n-steps of 128 each
+    cfg, params = _layer(150, 150, (1, 0, 1, 0), jnp.float32)
+    x = jax.random.normal(jax.random.key(4), (64, 150))
     t_flat = flatten_t(params["t"], cfg.kb)
     wt = fuse_wt(params["w_b"], t_flat, cfg.n_bases_kept)
     v1 = kan_fused_pallas(x, params["w_b"], t_flat, cfg.spec, cfg.kb,
-                          bm=32, bi=24, bn=32, interpret=True)
+                          bm=32, bi=128, bn=128, interpret=True)
     v2 = kan_fused_pallas_v2(x, wt, cfg.spec, cfg.kb,
-                             bm=32, bi=24, bn=32, interpret=True)
+                             bm=32, bi=128, bn=128, interpret=True)
     assert float(jnp.max(jnp.abs(v1 - v2))) <= 1e-5
 
 
@@ -153,10 +158,38 @@ def test_fused_weight_layout_row_interleave():
 @pytest.mark.parametrize("g,k", [(2, 1), (8, 2), (16, 4)])
 def test_v2_other_spline_specs(g, k):
     spec = SplineSpec(g, k)
-    cfg, params = _layer(40, 24, None, jnp.float32, spec=spec)
-    x = jax.random.normal(jax.random.key(5), (53, 40))
+    # n_in 150 -> two padded i-steps of 128
+    cfg, params = _layer(150, 24, None, jnp.float32, spec=spec)
+    x = jax.random.normal(jax.random.key(5), (53, 150))
     wt = kan_fused_weights(params, cfg)
     got = kan_fused_pallas_v2(x, wt, spec, cfg.kb,
-                              bm=32, bi=16, bn=16, interpret=True)
+                              bm=32, bi=128, bn=128, interpret=True)
     want = kan_layer_ref(x, params["w_b"], params["t"], spec)
     assert float(jnp.max(jnp.abs(got - want))) <= 1e-4
+
+
+@pytest.mark.parametrize("pattern", PATTERNS)
+def test_v2_q8_multi_step_vs_jnp_oracle(pattern):
+    """int8 kernel over several padded i- and n-steps (n_in 300 -> 3 of
+    128, n_out 150 -> 2) against the q8 jnp oracle."""
+    from repro.kernels.kan_fused.ops import kan_linear_q8
+
+    B, n_in, n_out = 37, 300, 150
+    cfg, params = _layer(n_in, n_out, pattern, jnp.float32)
+    nbk = cfg.n_bases_kept
+    wt = np.asarray(kan_fused_weights(params, cfg)).reshape(n_in, nbk + 1,
+                                                             n_out)
+    slot_scales = np.abs(wt).max(axis=(0, 2)) / 127.0        # per row slot
+    wt_q = np.round(wt / slot_scales[None, :, None]).astype(np.int8)
+    wt_q = jnp.asarray(wt_q.reshape(n_in * (nbk + 1), n_out))
+    x = np.asarray(jax.random.normal(jax.random.key(6), (B, n_in)))
+    x_scale = float(np.abs(x).max() / 127.0)
+    x_q = jnp.asarray(np.round(x / x_scale).astype(np.int8))
+    ss = tuple(float(s) for s in slot_scales)
+    got = kan_linear_q8(x_q, wt_q, ss, cfg.spec, cfg.kb, x_scale=x_scale,
+                        impl="pallas_interpret", blocks=(64, 128, 128))
+    want = kan_linear_q8(x_q, wt_q, ss, cfg.spec, cfg.kb, x_scale=x_scale,
+                         impl="jnp")
+    scale = float(jnp.max(jnp.abs(want))) + 1.0
+    err = float(jnp.max(jnp.abs(got - want)))
+    assert got.dtype == jnp.float32 and err <= 1e-4 * scale, (pattern, err)

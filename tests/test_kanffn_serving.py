@@ -144,6 +144,29 @@ def test_plain_arch_keeps_null_report(ci_setup):
     assert backend.batch_report(2) is None
 
 
+@pytest.mark.parametrize("kinds,precision,served", [
+    (None, "f32", "bfloat16"),          # plain arch keeps its config dtype
+    (None, "bf16", "bfloat16"),
+    (("mlp", "kan", "mlp"), "f32", "float32"),   # kan-ffn follows precision
+    (("mlp", "kan", "mlp"), "bf16", "bfloat16"),
+])
+def test_served_dtype(ci_setup, kinds, precision, served):
+    """The dtype a bf16-configured arch is served in, per precision."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    cfg, _ = ci_setup
+    cfg = dataclasses.replace(cfg, name="bf16-arch", ffn_kinds=kinds,
+                              ffn_masks=None, dtype="bfloat16")
+    params = T.init_params(jax.random.key(0), cfg)
+    backend = TransformerBackend(cfg, params, impl="jnp", precision=precision)
+    assert backend.cfg.dtype == served
+    floats = [a.dtype for a in jax.tree.leaves(backend.params)
+              if jnp.issubdtype(a.dtype, jnp.floating)]
+    assert floats and all(d == jnp.dtype(served) for d in floats)
+
+
 def test_masked_serving_runs(ci_setup):
     """Calibrated two-stage masks thread end to end: calibrate -> serve."""
     cfg, params = ci_setup

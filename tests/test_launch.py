@@ -178,3 +178,44 @@ def test_sharded_equals_dense_subprocess(arch):
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert abs(out["loss1"] - out["loss8"]) < 1e-3, out
     assert out["max_param_diff"] < 1e-3, out
+
+
+@pytest.fixture()
+def cache_config():
+    """Restore the compile-cache settings enable_compile_cache touches."""
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    yield
+    for k, v in saved.items():
+        jax.config.update(k, v)
+
+
+def test_compile_cache_defaults_to_fixed_path_in_checkout(
+        monkeypatch, cache_config):
+    from repro.utils import REPO_ROOT, enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.join(str(REPO_ROOT), ".jax_cache")
+    assert enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert os.path.realpath(str(REPO_ROOT)) == os.path.realpath(REPO)
+
+
+def test_compile_cache_env_dir_is_left_alone(monkeypatch, cache_config,
+                                             tmp_path):
+    from repro.utils import enable_compile_cache
+
+    jax.config.update("jax_compilation_cache_dir", None)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    # JAX reads the variable itself; the helper sets no directory of its own
+    assert jax.config.jax_compilation_cache_dir is None
+
+
+def test_autotune_cache_defaults_into_checkout(monkeypatch):
+    from repro.kernels import autotune
+
+    monkeypatch.delenv("REPRO_AUTOTUNE_CACHE", raising=False)
+    assert os.path.realpath(autotune.default_cache_path()) == \
+        os.path.realpath(os.path.join(REPO, ".autotune", "autotune.json"))
