@@ -34,6 +34,15 @@ bitwise identical (test-pinned).  The workload's ``ModePlan`` (core/modes)
 rides along: every served batch is charged its mode-switch schedule in the
 simulated-cycle report.
 
+While a JAX profiler trace runs, ``VikinBackend.step`` and
+``TransformerBackend.step`` / ``.prefill`` mark their phases as spans
+(runtime/spans.py): ``backend.step`` with its rows active and computed,
+split into ``.inputs``, ``.dispatch`` (the jitted call), ``.pick`` (the
+transformer's eager greedy pick), ``.readback`` and ``.outputs``;
+``backend.prefill`` with its request id and prompt tokens, split into
+``.dispatch``, ``.pick``, ``.readback`` (the first token) and ``.splice``
+(its cache into the slot).
+
 Implements the backend protocol and cycle-attribution contract of DESIGN.md
 Sec. 11; serving calibrated sparse checkpoints (``VikinBackend(masks=...)``,
 restored by checkpoint/restore_masks) follows the measurement protocol of
@@ -53,6 +62,7 @@ from repro.core.engine import (
     serving_report,
 )
 from repro.core.modes import RECONFIG_CYCLES, ExecMode, LayerKind, ModePlan
+from repro.runtime.spans import span
 from repro.utils import next_pow2 as _next_pow2
 
 
@@ -252,8 +262,10 @@ class TransformerBackend(ModelBackend):
         self.cfg, self.params = cfg, params
         self.precision = precision
         self._T, self._jax = T, jax
-        self._decode = jax.jit(
-            lambda p, tok, c: T.decode_step(p, cfg, tok, c))
+        def transformer_decode(p: Any, tok: Any, c: Any) -> Any:
+            return T.decode_step(p, cfg, tok, c)
+
+        self._decode = jax.jit(transformer_decode)
         # prefill is jitted per exact prompt length: no padding, so slot
         # caches carry the true per-request position (the per-row 'len').
         self._prefill_cache: Dict[int, Callable[..., Any]] = {}
@@ -277,10 +289,10 @@ class TransformerBackend(ModelBackend):
         if length not in self._prefill_cache:
             cfg, T = self.cfg, self._T
 
-            def fn(params: Any, tokens: Any) -> Any:
+            def transformer_prefill(params: Any, tokens: Any) -> Any:
                 return T.prefill(params, cfg, tokens, max_len=self.max_len)
 
-            self._prefill_cache[length] = self._jax.jit(fn)
+            self._prefill_cache[length] = self._jax.jit(transformer_prefill)
         return self._prefill_cache[length]
 
     def prefill_logits(self, tokens: np.ndarray) -> Tuple[Any, Any]:
@@ -300,22 +312,30 @@ class TransformerBackend(ModelBackend):
             # each prefilled prompt token is one model instance the cycle
             # model must charge on the NEXT tick's report
             self._pending_prefill += tokens.shape[1]
-        logits, cache = self.prefill_logits(tokens)
-        next_tok = int(jax.device_get(T.greedy_token(logits))[0, 0])
-        req.generated.append(next_tok)
+        with span("backend.prefill", rid=req.rid, tokens=tokens.shape[1]):
+            with span("backend.prefill.dispatch"):
+                logits, cache = self.prefill_logits(tokens)
+            with span("backend.prefill.pick"):
+                first = T.greedy_token(logits)
+            with span("backend.prefill.readback"):
+                next_tok = int(jax.device_get(first)[0, 0])
+            req.generated.append(next_tok)
 
-        def put(full: Any, new: Any) -> Any:
-            # find the batch dim: the dim where full is n_slots-wide and the
-            # fresh cache is 1-wide (dim 0 for plain, dim 1 under the layer
-            # stack).  Everything else (shapes) matches by construction.
-            for d in range(min(2, full.ndim)):
-                if (full.shape[d] == self.n_slots and d < new.ndim
-                        and new.shape[d] == 1):
-                    sl = tuple([slice(None)] * d + [slice(slot, slot + 1)])
-                    return full.at[sl].set(new.astype(full.dtype))
-            return full
+            def put(full: Any, new: Any) -> Any:
+                # find the batch dim: the dim where full is n_slots-wide and
+                # the fresh cache is 1-wide (dim 0 for plain, dim 1 under
+                # the layer stack).  Everything else (shapes) matches by
+                # construction.
+                for d in range(min(2, full.ndim)):
+                    if (full.shape[d] == self.n_slots and d < new.ndim
+                            and new.shape[d] == 1):
+                        sl = tuple([slice(None)] * d
+                                   + [slice(slot, slot + 1)])
+                        return full.at[sl].set(new.astype(full.dtype))
+                return full
 
-        return jax.tree.map(put, caches, cache)
+            with span("backend.prefill.splice"):
+                return jax.tree.map(put, caches, cache)
 
     def step(self, caches: Any,
              slot_req: Sequence[Optional[Request]]) -> Any:
@@ -323,18 +343,27 @@ class TransformerBackend(ModelBackend):
 
         jax, T = self._jax, self._T
         active = [s for s, r in enumerate(slot_req) if r is not None]
-        toks = np.zeros((self.n_slots, 1), np.int32)
-        for s in active:
-            toks[s, 0] = slot_req[s].generated[-1]
-        logits, caches = self._decode(self.params, jnp.asarray(toks), caches)
-        nxt = np.asarray(jax.device_get(T.greedy_token(logits)))
-        for s in active:
-            req = slot_req[s]
-            tok = int(nxt[s, 0])
-            req.generated.append(tok)
-            if (len(req.generated) >= req.max_new_tokens
-                    or (req.eos_id is not None and tok == req.eos_id)):
-                req.done = True
+        with span("backend.step", active=len(active), computed=self.n_slots):
+            with span("backend.step.inputs"):
+                toks = np.zeros((self.n_slots, 1), np.int32)
+                for s in active:
+                    toks[s, 0] = slot_req[s].generated[-1]
+                toks = jnp.asarray(toks)
+            with span("backend.step.dispatch"):
+                logits, caches = self._decode(self.params, toks, caches)
+            with span("backend.step.pick"):
+                picked = T.greedy_token(logits)
+            with span("backend.step.readback"):
+                nxt = np.asarray(jax.device_get(picked))
+            with span("backend.step.outputs"):
+                for s in active:
+                    req = slot_req[s]
+                    tok = int(nxt[s, 0])
+                    req.generated.append(tok)
+                    if (len(req.generated) >= req.max_new_tokens
+                            or (req.eos_id is not None
+                                and tok == req.eos_id)):
+                        req.done = True
         return caches
 
     def batch_report(self, n_active: int,
@@ -456,20 +485,23 @@ class VikinBackend(ModelBackend):
         from repro.models.ffn import vikin_stack_apply
 
         model, impl, masks = self.model, self.impl, self.masks
-        if self.precision == "int8":
-            from repro.core.quant import quant_stack_apply
+        precision, scales = self.precision, self.scales
 
-            scales = self.scales
-            return lambda p, x: quant_stack_apply(p, x, model, scales,
-                                                  impl=impl, masks=masks)
-        if self.precision == "bf16":
-            import jax.numpy as jnp
+        def vikin_forward(p: Any, x: Any) -> Any:
+            if precision == "int8":
+                from repro.core.quant import quant_stack_apply
 
-            return lambda p, x: vikin_stack_apply(
-                p, x.astype(jnp.bfloat16), model, impl=impl, masks=masks,
-            ).astype(jnp.float32)
-        return lambda p, x: vikin_stack_apply(p, x, model, impl=impl,
-                                              masks=masks)
+                return quant_stack_apply(p, x, model, scales, impl=impl,
+                                         masks=masks)
+            if precision == "bf16":
+                import jax.numpy as jnp
+
+                return vikin_stack_apply(
+                    p, x.astype(jnp.bfloat16), model, impl=impl,
+                    masks=masks).astype(jnp.float32)
+            return vikin_stack_apply(p, x, model, impl=impl, masks=masks)
+
+        return vikin_forward
 
     def init_state(self, n_slots: int, max_len: int) -> np.ndarray:
         self.n_slots = n_slots
@@ -510,13 +542,19 @@ class VikinBackend(ModelBackend):
              slot_req: Sequence[Optional[Request]]) -> np.ndarray:
         active = [s for s, r in enumerate(slot_req) if r is not None]
         bucket = self.bucket(len(active))
-        xb = np.zeros((bucket, self.n_in), np.float32)
-        for j, s in enumerate(active):
-            xb[j] = inputs[s]
-        y = np.asarray(self._fwd(self.params, xb))
-        for j, s in enumerate(active):
-            slot_req[s].output = y[j].copy()
-            slot_req[s].done = True
+        with span("backend.step", active=len(active), computed=bucket):
+            with span("backend.step.inputs"):
+                xb = np.zeros((bucket, self.n_in), np.float32)
+                for j, s in enumerate(active):
+                    xb[j] = inputs[s]
+            with span("backend.step.dispatch"):
+                y = self._fwd(self.params, xb)
+            with span("backend.step.readback"):
+                y = np.asarray(y)
+            with span("backend.step.outputs"):
+                for j, s in enumerate(active):
+                    slot_req[s].output = y[j].copy()
+                    slot_req[s].done = True
         return inputs
 
     def batch_report(self, n_active: int,

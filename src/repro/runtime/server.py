@@ -35,7 +35,16 @@ as p50/p95/p99 via ``latency_stats()`` / merged into ``stats`` by
 ``run_until_done``.  All request timestamps and deadline checks read
 ``self.clock`` (default ``time.perf_counter``); the open-loop trace
 harness (runtime/loadgen.py) swaps in a deterministic simulated clock, so
-deadline semantics hold identically in wall and simulated time.
+deadline semantics hold identically in wall and simulated time.  A
+request's queue wait ends when the policy selects it; its own prefill is
+service time.
+
+While a JAX profiler trace runs, each tick is an ``engine.iter`` span
+holding ``engine.expire``, ``engine.admit`` (its queue depth and free
+slots as arguments; ``engine.select`` inside), ``engine.report`` and
+``engine.retire`` and the backend's own spans, and every admitted request
+leaves an ``engine.queue`` span from its submit stamp to its selection
+(runtime/spans.py).
 """
 from __future__ import annotations
 
@@ -55,6 +64,7 @@ from repro.runtime.scheduler import (
     get_policy,
     shed_candidate,
 )
+from repro.runtime.spans import record, span
 
 
 class AdmissionError(RuntimeError):
@@ -296,72 +306,88 @@ class Engine:
 
     def _admit(self) -> None:
         free = [s for s, r in enumerate(self.slot_req) if r is None]
-        if not free or not self._queued():
-            return
-        ctx = SchedContext(
-            queues=self._queues, free_slots=len(free),
-            active=frozenset(r.workload for r in self.slot_req
-                             if r is not None),
-            hw_mode=self.hw_mode, plans=self._plans(),
-            bucket_for=self._bucket_for, max_queue=self.max_queue,
-            pinned_modes=getattr(self.backend, "pinned_modes", None),
-            now=self.clock())
-        picked = self.policy.select(ctx)
-        for req, slot in zip(picked, free):
-            self._queues[req.workload].remove(req)
-            self.state = self.backend.prefill(self.state, slot, req)
-            self.slot_req[slot] = req
-            req.t_admit = self.clock()
-            req.sim_admit = self.stats["sim_latency_s"]
-            self._sample("queue_wait_wall", req.t_admit - req.t_submit)
-            self._sample("queue_wait_sim", req.sim_admit - req.sim_submit)
+        queued = self._queued() if free else 0
+        with span("engine.admit", queued=queued, free=len(free)):
+            if not queued:
+                return
+            ctx = SchedContext(
+                queues=self._queues, free_slots=len(free),
+                active=frozenset(r.workload for r in self.slot_req
+                                 if r is not None),
+                hw_mode=self.hw_mode, plans=self._plans(),
+                bucket_for=self._bucket_for, max_queue=self.max_queue,
+                pinned_modes=getattr(self.backend, "pinned_modes", None),
+                now=self.clock())
+            with span("engine.select"):
+                picked = self.policy.select(ctx)
+            for req, slot in zip(picked, free):
+                self._queues[req.workload].remove(req)
+                # the wait ends at selection: the request's own prefill
+                # is service time
+                req.t_admit = self.clock()
+                req.sim_admit = self.stats["sim_latency_s"]
+                if self.clock is time.perf_counter:   # the spans' clock
+                    record("engine.queue", req.t_submit, req.t_admit)
+                self._sample("queue_wait_wall", req.t_admit - req.t_submit)
+                self._sample("queue_wait_sim",
+                             req.sim_admit - req.sim_submit)
+                self.state = self.backend.prefill(self.state, slot, req)
+                self.slot_req[slot] = req
 
     def tick(self) -> None:
         """One engine iteration: expire dead queued work, admit requests,
         run one batched step for all active slots, recycle finished slots,
         re-admit into the freed slots.  Times itself, so ``throughput()``
         reports wall figures whether the engine is driven here or through
-        ``run_until_done``."""
+        ``run_until_done``.  Each phase is a span (runtime/spans.py)."""
         t0 = time.perf_counter()
-        self._expire_queued()
-        self._admit()
-        active = [s for s, r in enumerate(self.slot_req) if r is not None]
-        if not active:
-            return
-        self.state = self.backend.step(self.state, self.slot_req)
-        self.stats["ticks"] += 1
-        rep = self.backend.batch_report(len(active), prev_mode=self.hw_mode)
-        if rep is not None:
-            rep = dict(rep)
-            exit_mode = rep.pop("exit_mode", None)
-            if exit_mode is not None:
-                self.hw_mode = exit_mode
-            for k, v in rep.items():
-                self.stats[k] = self.stats.get(k, 0.0) + v
-        # read the clock AFTER the batch report: under a simulated clock
-        # (loadgen.SimClock tracks sim_latency_s) completions are stamped
-        # at the batch's simulated end, not its start
-        now = self.clock()
-        for s in active:
-            req = self.slot_req[s]
-            if req.done:
-                self.stats["served"] += 1
-                req.t_done, req.sim_done = now, self.stats["sim_latency_s"]
-                self._sample("service_wall", now - req.t_admit)
-                self._sample("service_sim", req.sim_done - req.sim_admit)
-                if req.deadline_s is not None:
-                    if req.miss_counted:      # went late while queued
-                        req.met_deadline = False
-                    else:
-                        req.met_deadline = (now - req.t_submit
-                                            <= req.deadline_s)
-                        if not req.met_deadline:
-                            self._count_miss(req)
-                self.slot_req[s] = None
-        # re-admit into freed slots NOW: admission only at tick start left
-        # recycled slots idle for a whole tick under a saturated queue
-        self._admit()
-        self.stats["wall_s"] += time.perf_counter() - t0
+        with span("engine.iter"):
+            with span("engine.expire"):
+                self._expire_queued()
+            self._admit()
+            active = [s for s, r in enumerate(self.slot_req) if r is not None]
+            if not active:
+                return
+            self.state = self.backend.step(self.state, self.slot_req)
+            self.stats["ticks"] += 1
+            with span("engine.report"):
+                rep = self.backend.batch_report(len(active),
+                                                prev_mode=self.hw_mode)
+                if rep is not None:
+                    rep = dict(rep)
+                    exit_mode = rep.pop("exit_mode", None)
+                    if exit_mode is not None:
+                        self.hw_mode = exit_mode
+                    for k, v in rep.items():
+                        self.stats[k] = self.stats.get(k, 0.0) + v
+            with span("engine.retire"):
+                # read the clock AFTER the batch report: under a simulated
+                # clock (loadgen.SimClock tracks sim_latency_s) completions
+                # are stamped at the batch's simulated end, not its start
+                now = self.clock()
+                for s in active:
+                    req = self.slot_req[s]
+                    if req.done:
+                        self.stats["served"] += 1
+                        req.t_done = now
+                        req.sim_done = self.stats["sim_latency_s"]
+                        self._sample("service_wall", now - req.t_admit)
+                        self._sample("service_sim",
+                                     req.sim_done - req.sim_admit)
+                        if req.deadline_s is not None:
+                            if req.miss_counted:  # went late while queued
+                                req.met_deadline = False
+                            else:
+                                req.met_deadline = (now - req.t_submit
+                                                    <= req.deadline_s)
+                                if not req.met_deadline:
+                                    self._count_miss(req)
+                        self.slot_req[s] = None
+            # re-admit into freed slots NOW: admission only at tick start
+            # left recycled slots idle for a whole tick under a saturated
+            # queue
+            self._admit()
+            self.stats["wall_s"] += time.perf_counter() - t0
 
     def run_until_done(self, max_ticks: int = 1000) -> Dict[int, list]:
         """Drive ticks until queue and slots drain; returns {rid: result}

@@ -15,7 +15,14 @@ engine buckets 2 and 16, and the qwen2-0.5b-kanffn FFN (KAN 896->1080 up;
 rows.  The topology is described inside a fixture, never at import, so
 every pytest-xdist worker collects the same tests and only the worker that
 runs this file loads the TPU library.
+
+The served programs themselves are compiled too, under their stable names
+(``transformer_decode``, ``transformer_prefill`` at the full
+qwen2-0.5b-kanffn width, ``vikin_forward`` of vikin-mixed): the device
+trace's readers find the kernels by the instruction names checked here.
 """
+import dataclasses
+import functools
 import os
 
 import jax
@@ -23,6 +30,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.registry import KANFFN_ARCHS
 from repro.configs.vikin_models import VIKIN_ARCHS
 from repro.core.splines import SplineSpec
 from repro.kernels import autotune
@@ -38,7 +46,9 @@ from repro.kernels.pattern_matmul.pattern_matmul import (
     matmul_compact_pallas,
     matmul_q8_pallas,
 )
-from repro.models.ffn import stack_layer_cfgs
+from repro.models import transformer as T
+from repro.models.ffn import stack_layer_cfgs, vikin_stack_init
+from repro.runtime.backends import TransformerBackend, VikinBackend
 
 
 def _paper_layers():
@@ -145,3 +155,48 @@ def test_largest_autotune_candidate_compiles(one_chip, dtype):
     assert vmem_bytes(big["bm"], big["bi"], big["bn"], nbk,
                       dtype) <= VMEM_LIMIT
     _kan_compile(one_chip, 128, n_in, n_out, nbk, dtype, big)
+
+
+def _on(one_chip, tree):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip), tree)
+
+
+def _served_program(one_chip, name):
+    """(jitted program, argument shapes) as the backend serves ``name``:
+    the compiled kernels (``impl="pallas"``), 8 slots."""
+    if name == "vikin_forward":
+        model = VIKIN_ARCHS["vikin-mixed"]
+        params = jax.eval_shape(functools.partial(
+            vikin_stack_init, model=model), jax.random.key(0))
+        b = VikinBackend(model, _on(one_chip, params), impl="pallas")
+        x = jax.ShapeDtypeStruct((8, b.n_in), jnp.float32, sharding=one_chip)
+        return b._fwd, (b.params, x)
+    arch = dataclasses.replace(KANFFN_ARCHS["qwen2-0.5b-kanffn"],
+                               dtype="bfloat16")
+    params = jax.eval_shape(functools.partial(T.init_params, cfg=arch),
+                            jax.random.key(0))
+    b = TransformerBackend(arch, _on(one_chip, params), impl="pallas",
+                           precision="bf16")
+    b.n_slots, b.max_len = 8, 64
+    if name == "transformer_prefill":
+        toks = jax.ShapeDtypeStruct((1, 16), jnp.int32, sharding=one_chip)
+        return b._prefill_fn(16), (b.params, toks)
+    caches = jax.eval_shape(lambda: T.init_caches(b.cfg, 8, 64))
+    toks = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
+    return b._decode, (b.params, toks, _on(one_chip, caches))
+
+
+@pytest.mark.parametrize("name", ["transformer_decode",
+                                  "transformer_prefill", "vikin_forward"])
+def test_served_program_holds_the_named_kernels(one_chip, name):
+    """The served programs keep their names and both Mosaic kernels under
+    the instruction names ``kan_roofline`` / ``pmm_roofline`` search for:
+    a rename must fail here, not leave those metrics silently empty."""
+    fn, args = _served_program(one_chip, name)
+    text = fn.lower(*args).compile().as_text()
+    assert text.startswith(f"HloModule jit_{name},")
+    instrs = [line.split(" = ")[0] for line in text.splitlines()
+              if " = " in line]
+    for kernel in ("kan_fused_pallas_v2", "matmul_compact_pallas"):
+        assert any(kernel in i for i in instrs), kernel
