@@ -40,8 +40,9 @@ While a JAX profiler trace runs, ``VikinBackend.step`` and
 split into ``.inputs``, ``.dispatch`` (the jitted call), ``.pick`` (the
 transformer's eager greedy pick), ``.readback`` and ``.outputs``;
 ``backend.prefill`` with its request id and prompt tokens, split into
-``.dispatch``, ``.pick``, ``.readback`` (the first token) and ``.splice``
-(its cache into the slot).
+``.dispatch``, ``.pick``, ``.splice`` (its cache into the slot: one
+jitted ``transformer_splice`` call, dispatched before the first token's
+``.readback`` so its host time overlaps the prefill on the device).
 
 Implements the backend protocol and cycle-attribution contract of DESIGN.md
 Sec. 11; serving calibrated sparse checkpoints (``VikinBackend(masks=...)``,
@@ -266,6 +267,24 @@ class TransformerBackend(ModelBackend):
             return T.decode_step(p, cfg, tok, c)
 
         self._decode = jax.jit(transformer_decode)
+
+        def transformer_splice(caches: Any, cache: Any, slot: Any) -> Any:
+            # write the fresh batch-1 cache into lane ``slot`` of every
+            # leaf: the batch dim is the first of dims 0, 1 where the slot
+            # caches are n_slots wide and the fresh one 1 wide (dim 0 for
+            # the extra list, dim 1 under the unit stack).  Decided from
+            # the shapes at trace time; ``slot`` is traced, so one
+            # executable serves every slot.
+            def put(full: Any, new: Any) -> Any:
+                for d in range(min(2, full.ndim, new.ndim)):
+                    if full.shape[d] == self.n_slots and new.shape[d] == 1:
+                        return jax.lax.dynamic_update_slice_in_dim(
+                            full, new.astype(full.dtype), slot, axis=d)
+                return full
+
+            return jax.tree.map(put, caches, cache)
+
+        self._splice = jax.jit(transformer_splice)
         # prefill is jitted per exact prompt length: no padding, so slot
         # caches carry the true per-request position (the per-row 'len').
         self._prefill_cache: Dict[int, Callable[..., Any]] = {}
@@ -317,25 +336,12 @@ class TransformerBackend(ModelBackend):
                 logits, cache = self.prefill_logits(tokens)
             with span("backend.prefill.pick"):
                 first = T.greedy_token(logits)
+            with span("backend.prefill.splice"):
+                caches = self._splice(caches, cache, np.int32(slot))
             with span("backend.prefill.readback"):
                 next_tok = int(jax.device_get(first)[0, 0])
             req.generated.append(next_tok)
-
-            def put(full: Any, new: Any) -> Any:
-                # find the batch dim: the dim where full is n_slots-wide and
-                # the fresh cache is 1-wide (dim 0 for plain, dim 1 under
-                # the layer stack).  Everything else (shapes) matches by
-                # construction.
-                for d in range(min(2, full.ndim)):
-                    if (full.shape[d] == self.n_slots and d < new.ndim
-                            and new.shape[d] == 1):
-                        sl = tuple([slice(None)] * d
-                                   + [slice(slot, slot + 1)])
-                        return full.at[sl].set(new.astype(full.dtype))
-                return full
-
-            with span("backend.prefill.splice"):
-                return jax.tree.map(put, caches, cache)
+        return caches
 
     def step(self, caches: Any,
              slot_req: Sequence[Optional[Request]]) -> Any:

@@ -6,12 +6,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs.registry import get_config
+from repro.configs.registry import KANFFN_ARCHS, get_config
 from repro.data.lm import LMDataConfig, Prefetcher, SyntheticLM
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import StepOptions
 from repro.models import transformer as T
-from repro.runtime.server import Server
+from repro.runtime.backends import TransformerBackend
+from repro.runtime.server import Engine, Server
 from repro.runtime.trainer import SimulatedFailure, Trainer, TrainerConfig
 
 
@@ -142,6 +143,127 @@ def test_server_slot_reuse():
     r2 = srv.submit(np.array([9, 8, 7], np.int32), max_new_tokens=3)
     out = srv.run_until_done()
     assert len(out[r1]) == 3 and len(out[r2]) == 3
+
+
+def _manual_decode(cfg, params, prompt, n_new, max_len):
+    """Greedy prefill + decode_step of one request alone (batch 1)."""
+    logits, caches = T.prefill(params, cfg, jnp.asarray(prompt[None, :]),
+                               max_len=max_len)
+    decode = jax.jit(lambda p, t, c: T.decode_step(p, cfg, t, c))
+    toks = [int(T.greedy_token(logits)[0, 0])]
+    while len(toks) < n_new:
+        lg, caches = decode(params, jnp.asarray([[toks[-1]]], jnp.int32),
+                            caches)
+        toks.append(int(T.greedy_token(lg)[0, 0]))
+    return toks
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "kanffn-ci"])
+def test_server_every_slot_and_reused_slot_match_manual_decode(arch):
+    """Prefills spliced into slots 1 and 2, and into slots freed by a
+    finished request, decode what each request decodes alone."""
+    if arch == "kanffn-ci":
+        cfg, impl = KANFFN_ARCHS[arch], "jnp"
+    else:
+        cfg, impl = get_config(arch).reduce(n_layers=2, d_model=32, d_ff=64,
+                                            vocab_size=64), None
+    be = TransformerBackend(cfg, T.init_params(jax.random.key(0), cfg),
+                            impl=impl)
+    slots = []
+    prefill = be.prefill
+
+    def recording_prefill(caches, slot, req):
+        slots.append(slot)
+        return prefill(caches, slot, req)
+
+    be.prefill = recording_prefill
+    eng = Engine(be, n_slots=3, max_len=32)
+    rng = np.random.default_rng(3)
+    work = [(rng.integers(0, cfg.vocab_size, size=n).astype(np.int32), m)
+            for n, m in ((4, 2), (6, 5), (4, 3), (6, 4), (4, 3))]
+    rids = [eng.submit(p, max_new_tokens=m) for p, m in work]
+    out = eng.run_until_done()
+    assert sorted(set(slots)) == [0, 1, 2] and len(slots) == len(work)
+    for (p, m), rid in zip(work, rids):
+        assert out[rid] == _manual_decode(be.cfg, be.params, p, m, 32), rid
+
+
+def _random_tree(tree, seed):
+    """``tree``'s shapes and dtypes, filled with seeded values, so a lane
+    written to the wrong place or in the wrong dtype shows."""
+    leaves, treedef = jax.tree.flatten(tree)
+    rng = np.random.default_rng(seed)
+    out = []
+    for a in leaves:
+        if jnp.issubdtype(a.dtype, jnp.integer):
+            v = rng.integers(-100, 100, size=a.shape)
+        else:
+            v = rng.standard_normal(a.shape)
+        out.append(jnp.asarray(v, a.dtype))
+    return jax.tree.unflatten(treedef, out)
+
+
+def _eager_splice(full_tree, new_tree, slot, n_slots):
+    """The per-leaf ``.at[].set`` the jitted splice replaced."""
+    def put(full, new):
+        for d in range(min(2, full.ndim)):
+            if (full.shape[d] == n_slots and d < new.ndim
+                    and new.shape[d] == 1):
+                sl = tuple([slice(None)] * d + [slice(slot, slot + 1)])
+                return full.at[sl].set(new.astype(full.dtype))
+        return full
+    return jax.tree.map(put, full_tree, new_tree)
+
+
+# batch on dim 0 in the extra list (with 'len'); on dim 1 under the unit
+# stack; int8 KV with fp16 scales; recurrent, xLSTM and cross-attention caches
+SPLICE_ARCHS = {
+    "kanffn-ci": lambda: KANFFN_ARCHS["kanffn-ci"],
+    "qwen2-0.5b": lambda: get_config("qwen2-0.5b").reduce(),
+    "qwen2-0.5b-kv-int8": lambda: get_config("qwen2-0.5b").reduce(
+        kv_quant=True),
+    "recurrentgemma-9b": lambda: get_config("recurrentgemma-9b").reduce(),
+    "xlstm-125m": lambda: get_config("xlstm-125m").reduce(),
+    "whisper-medium": lambda: get_config("whisper-medium").reduce(),
+}
+SPLICE_SLOTS = 4
+
+
+def _splice_setup(arch):
+    """(backend, slot caches, fresh batch-1 cache), both filled with seeded
+    values of the served dtypes."""
+    cfg = SPLICE_ARCHS[arch]()
+    be = TransformerBackend(cfg, T.init_params(jax.random.key(0), cfg))
+    full = _random_tree(be.init_state(SPLICE_SLOTS, 16), 0)
+    new = _random_tree(T.init_caches(be.cfg, 1, 16), 1)
+    return be, full, new
+
+
+@pytest.mark.parametrize("slot", range(SPLICE_SLOTS))
+@pytest.mark.parametrize("arch", sorted(SPLICE_ARCHS))
+def test_transformer_splice_bitwise_equals_eager_set(arch, slot):
+    be, full, new = _splice_setup(arch)
+    got = be._splice(full, new, np.int32(slot))
+    want = _eager_splice(full, new, slot, SPLICE_SLOTS)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w, f in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                       jax.tree.leaves(full)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        # every leaf of these archs has a slot lane: none passes through
+        assert not np.array_equal(np.asarray(g), np.asarray(f))
+
+
+@pytest.mark.parametrize("arch", sorted(SPLICE_ARCHS))
+def test_transformer_splice_one_program_serves_every_slot(arch):
+    be, full, new = _splice_setup(arch)
+    got, want = full, full
+    for slot in range(SPLICE_SLOTS):
+        got = be._splice(got, new, np.int32(slot))
+        want = _eager_splice(want, new, slot, SPLICE_SLOTS)
+    assert be._splice._cache_size() == 1
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 # ---------------------------------------------------------------------------

@@ -20,10 +20,13 @@ The served programs themselves are compiled too, under their stable names
 (``transformer_decode``, ``transformer_prefill`` at the full
 qwen2-0.5b-kanffn width, ``vikin_forward`` of vikin-mixed): the device
 trace's readers find the kernels by the instruction names checked here.
+So is the prefill's cache splice, ``transformer_splice``, at the served
+slot caches, with the slot a runtime argument.
 """
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -162,6 +165,19 @@ def _on(one_chip, tree):
         s.shape, s.dtype, sharding=one_chip), tree)
 
 
+def _served_transformer(one_chip, max_len):
+    """qwen2-0.5b-kanffn as the backend serves it: bf16, the compiled
+    kernels (``impl="pallas"``), 8 slots of ``max_len``."""
+    arch = dataclasses.replace(KANFFN_ARCHS["qwen2-0.5b-kanffn"],
+                               dtype="bfloat16")
+    params = jax.eval_shape(functools.partial(T.init_params, cfg=arch),
+                            jax.random.key(0))
+    b = TransformerBackend(arch, _on(one_chip, params), impl="pallas",
+                           precision="bf16")
+    b.n_slots, b.max_len = 8, max_len
+    return b
+
+
 def _served_program(one_chip, name):
     """(jitted program, argument shapes) as the backend serves ``name``:
     the compiled kernels (``impl="pallas"``), 8 slots."""
@@ -172,13 +188,7 @@ def _served_program(one_chip, name):
         b = VikinBackend(model, _on(one_chip, params), impl="pallas")
         x = jax.ShapeDtypeStruct((8, b.n_in), jnp.float32, sharding=one_chip)
         return b._fwd, (b.params, x)
-    arch = dataclasses.replace(KANFFN_ARCHS["qwen2-0.5b-kanffn"],
-                               dtype="bfloat16")
-    params = jax.eval_shape(functools.partial(T.init_params, cfg=arch),
-                            jax.random.key(0))
-    b = TransformerBackend(arch, _on(one_chip, params), impl="pallas",
-                           precision="bf16")
-    b.n_slots, b.max_len = 8, 64
+    b = _served_transformer(one_chip, max_len=64)
     if name == "transformer_prefill":
         toks = jax.ShapeDtypeStruct((1, 16), jnp.int32, sharding=one_chip)
         return b._prefill_fn(16), (b.params, toks)
@@ -200,3 +210,23 @@ def test_served_program_holds_the_named_kernels(one_chip, name):
               if " = " in line]
     for kernel in ("kan_fused_pallas_v2", "matmul_compact_pallas"):
         assert any(kernel in i for i in instrs), kernel
+
+
+def test_splice_is_one_program_with_the_slot_a_runtime_argument(one_chip):
+    """The prefill's cache splice compiles as ``jit_transformer_splice`` at
+    the served shapes (8 slots of 2064, the longprompt cell's), and takes the
+    slot as an s32[] parameter: one executable serves every slot."""
+    b = _served_transformer(one_chip, max_len=2064)
+    caches = jax.eval_shape(lambda: T.init_caches(b.cfg, 8, 2064))
+    cache = jax.eval_shape(lambda: T.init_caches(b.cfg, 1, 2064))
+    slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    text = b._splice.lower(_on(one_chip, caches), _on(one_chip, cache),
+                           slot).compile().as_text()
+    assert text.startswith("HloModule jit_transformer_splice,")
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    params = [line for line in entry.splitlines() if " parameter(" in line]
+    assert len(params) == 2 * len(jax.tree.leaves(caches)) + 1
+    slot_params = [line for line in params
+                   if re.search(r"= s32\[\]\S* parameter\(", line)]
+    assert len(slot_params) == 1 and 'op_name="slot"' in slot_params[0]
