@@ -45,7 +45,7 @@ class ArchConfig:
     # norms / ffn / embedding
     norm: str = "rms"                  # rms | ln
     norm_offset: float = 0.0           # gemma-style (1 + scale)
-    ffn_kind: str = "swiglu"           # swiglu | geglu | mlp | kan
+    ffn_kind: str = "swiglu"           # swiglu | geglu | mlp | kan | kanffn
     act: str = "gelu"                  # for ffn_kind == mlp
     ffn_bias: bool = False
     tied_embeddings: bool = True
@@ -55,6 +55,10 @@ class ArchConfig:
     top_k: int = 0
     shared_expert: bool = False
     capacity_factor: float = 1.25
+    # expert parallelism's share of one chip: (first expert id, count) of
+    # the experts each MoE layer holds here; None = all of them.  Only the
+    # KAN-FFN expert layer (ffn_kind "kanffn") serves a share.
+    held_experts: Optional[Tuple[int, int]] = None
     # layer pattern for hybrid stacks; None => all-attention
     block_pattern: Optional[Tuple[str, ...]] = None  # attn|rec|mlstm|slstm
     # modality frontends (STUBS: input_specs provides embeddings)
@@ -90,6 +94,14 @@ class ArchConfig:
 
     # ------------------------------------------------------------ validate
     def __post_init__(self):
+        if self.held_experts is not None:
+            first, count = self.held_experts
+            if (self.ffn_kind != "kanffn" or count < 1 or first < 0
+                    or first + count > self.n_experts):
+                raise ArchConfigError(
+                    f"{self.name}: held_experts {self.held_experts} needs "
+                    f"ffn_kind 'kanffn' and a share of the {self.n_experts} "
+                    "experts")
         if self.ffn_kinds is None:
             if self.ffn_masks is not None:
                 raise ArchConfigError(
@@ -196,13 +208,17 @@ class ArchConfig:
 
     def moe_cfg(self) -> MoEConfig:
         # ffn_kind="kan" turns every expert into a KAN stack -- the paper's
-        # technique inside MoE experts (DESIGN.md Sec. 5)
+        # technique inside MoE experts (DESIGN.md Sec. 5); "kanffn" makes
+        # each a KAN-FFN, served by the dropless grouped layer
+        first, count = self.held_experts or (0, None)
         return MoEConfig(
             d_model=self.d_model, d_ff=self.d_ff, n_experts=self.n_experts,
             top_k=self.top_k, capacity_factor=self.capacity_factor,
             shared_expert=self.shared_expert,
-            ffn_kind="kan" if self.ffn_kind == "kan" else "swiglu",
-            kan_grid=self.kan_grid, kan_order=self.kan_order)
+            ffn_kind=(self.ffn_kind if self.ffn_kind in ("kan", "kanffn")
+                      else "swiglu"),
+            kan_grid=self.kan_grid, kan_order=self.kan_order,
+            first_expert=first, n_held=count, impl=self.ffn_impl)
 
     def xlstm_cfg(self) -> XLSTMConfig:
         return XLSTMConfig(d_model=self.d_model, n_heads=self.n_heads)

@@ -42,6 +42,25 @@ KANFFN_ARCHS: Dict[str, ArchConfig] = {
                         for i in range(qwen2_0_5b.CONFIG.n_layers)),
         scan_layers=False,
     ),
+    # qwen3-moe-235b-a22b with KAN-FFN experts, cut to one chip's share
+    # of a 48-chip deployment (4-way expert parallelism x 12 pipeline
+    # stages of 8 layers): 8 layers, experts 0-31 of every layer's 128,
+    # published widths (PERF.md section 4)
+    "qwen3-moe-235b-a22b-kanffn": dataclasses.replace(
+        qwen3_moe_235b_a22b.CONFIG,
+        name="qwen3-moe-235b-a22b-kanffn",
+        n_layers=8, ffn_kinds=("moe",) * 8, ffn_kind="kanffn",
+        held_experts=(0, 32), scan_layers=False,
+    ),
+    # its CPU-test sibling: the same blocks at d_model 64, 16 experts,
+    # top-4, 4 held
+    "kanffn-moe-ci": ArchConfig(
+        name="kanffn-moe-ci", family="moe", n_layers=2, d_model=64,
+        n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128, vocab_size=256,
+        n_experts=16, top_k=4, qk_norm=True, tied_embeddings=False,
+        ffn_kinds=("moe", "moe"), ffn_kind="kanffn", held_experts=(0, 4),
+        scan_layers=False, dtype="float32", remat=False, loss_chunks=1,
+    ),
     # xlstm-125m-class CI variant: small enough to serve train-free in the
     # smoke lane, mixed kinds so the ModePlan has real flips to pin
     "kanffn-ci": ArchConfig(

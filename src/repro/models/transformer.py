@@ -23,7 +23,7 @@ Everything is functional: params/caches are pytrees, apply fns are pure.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -104,12 +104,17 @@ def block_init(key, cfg: ArchConfig, kind: str, *, cross: bool = False,
 
 
 def _apply_ffn_part(p: Params, x, cfg: ArchConfig, layer: int = 0,
-                    taps: Optional[Dict[int, jax.Array]] = None):
+                    taps: Optional[Dict[int, jax.Array]] = None,
+                    counters: Optional[List] = None):
+    """``counters``, where given, collects the layer's MoE counts (the
+    KAN-FFN expert layer's, models/moe.moe_kanffn) while it is traced."""
     aux = jnp.zeros((), jnp.float32)
     if "moe" in p:
         r = M.moe_apply(p["moe"], _norm(cfg, p["moe_norm"], x), cfg.moe_cfg())
         x = x + r["out"]
         aux = r["aux_loss"]
+        if counters is not None and "counts" in r:
+            counters.append(r["counts"])
     elif "ffn" in p:
         xn = _norm(cfg, p["ffn_norm"], x)
         if taps is not None:
@@ -172,7 +177,8 @@ def block_init_cache(batch: int, max_len: int, cfg: ArchConfig, kind: str,
 
 
 def block_prefill(p: Params, x, cfg: ArchConfig, kind: str, max_len: int, *,
-                  prefix_len=None, memory=None, layer: int = 0):
+                  prefix_len=None, memory=None, layer: int = 0,
+                  counters: Optional[List] = None):
     """Full-sequence pass that also returns the decode cache."""
     if kind == "attn":
         acfg = cfg.attn_cfg()
@@ -192,7 +198,7 @@ def block_prefill(p: Params, x, cfg: ArchConfig, kind: str, max_len: int, *,
                 B, Sk, acfg.n_kv_heads, hd)
             cache["cv"] = dense(p["cross"]["wv"], memory).reshape(
                 B, Sk, acfg.n_kv_heads, hd)
-        x, _ = _apply_ffn_part(p, x, cfg, layer)
+        x, _ = _apply_ffn_part(p, x, cfg, layer, counters=counters)
         return x, cache
     if kind == "rec":
         y, st = R.rglru_apply(p["rec"], _norm(cfg, p["rec_norm"], x),
@@ -220,7 +226,7 @@ def _cross_decode(p, x1, cache, acfg: A.AttnConfig):
 
 
 def block_decode(p: Params, x1, cfg: ArchConfig, kind: str, cache: Params,
-                 *, layer: int = 0):
+                 *, layer: int = 0, counters: Optional[List] = None):
     """One-token step.  Returns (x1, new_cache)."""
     if kind == "attn":
         acfg = cfg.attn_cfg()
@@ -234,7 +240,7 @@ def block_decode(p: Params, x1, cfg: ArchConfig, kind: str, cache: Params,
         if "cross" in p and "ck" in cache:
             x1 = x1 + _cross_decode(
                 p["cross"], _norm(cfg, p["cross_norm"], x1), cache, acfg)
-        x1, _ = _apply_ffn_part(p, x1, cfg, layer)
+        x1, _ = _apply_ffn_part(p, x1, cfg, layer, counters=counters)
         return x1, new_cache
     if kind == "rec":
         y, st = R.rglru_decode_step(
@@ -473,8 +479,10 @@ def prefill(
     frames: Optional[jax.Array] = None,
     patches: Optional[jax.Array] = None,
     max_len: Optional[int] = None,
+    counters: Optional[List] = None,
 ) -> Tuple[jax.Array, Params]:
-    """Full forward + cache build.  Returns (last-position logits, caches)."""
+    """Full forward + cache build.  Returns (last-position logits, caches).
+    ``counters`` collects the unscanned layers' MoE counts."""
     B, S = tokens.shape
     x = _embed_in(params, cfg, tokens)
     prefix_len = None
@@ -520,7 +528,7 @@ def prefill(
     for i, bp in enumerate(params["extra"]):
         x, c = block_prefill(bp, x, cfg, _block_kind(cfg, off + i), max_len,
                              prefix_len=prefix_len, memory=memory,
-                             layer=off + i)
+                             layer=off + i, counters=counters)
         caches["extra"].append(c)
     h = _norm(cfg, params["final_norm"], x)
     return _logits(params, cfg, h[:, -1:]), caches
@@ -531,8 +539,10 @@ def decode_step(
     cfg: ArchConfig,
     token: jax.Array,                 # (B, 1) last sampled token
     caches: Params,
+    counters: Optional[List] = None,
 ) -> Tuple[jax.Array, Params]:
-    """One token for the whole stack.  Returns (logits (B,1,V), caches)."""
+    """One token for the whole stack.  Returns (logits (B,1,V), caches).
+    ``counters`` collects the unscanned layers' MoE counts."""
     x = _embed_in(params, cfg, token)
 
     def unit(x, scanned):
@@ -563,7 +573,8 @@ def decode_step(
     off = n_units * len(cfg.pattern)
     for i, bp in enumerate(params["extra"]):
         x, c = block_decode(bp, x, cfg, _block_kind(cfg, off + i),
-                            caches["extra"][i], layer=off + i)
+                            caches["extra"][i], layer=off + i,
+                            counters=counters)
         new_caches["extra"].append(c)
     h = _norm(cfg, params["final_norm"], x)
     return _logits(params, cfg, h), new_caches

@@ -42,7 +42,12 @@ transformer's eager greedy pick), ``.readback`` and ``.outputs``;
 ``backend.prefill`` with its request id and prompt tokens, split into
 ``.dispatch``, ``.pick``, ``.splice`` (its cache into the slot: one
 jitted ``transformer_splice`` call, dispatched before the first token's
-``.readback`` so its host time overlaps the prefill on the device).
+``.readback`` so its host time overlaps the prefill on the device).  An
+arch with KAN-FFN expert layers also counts, on ``backend.prefill`` and
+``backend.step``, the rows its jitted program ``routed`` to the experts
+held here and the held experts ``active`` (each summed over the layers),
+and the largest group, ``max_group``: read back with the tokens, in the
+same ``device_get``.
 
 Implements the backend protocol and cycle-attribution contract of DESIGN.md
 Sec. 11; serving calibrated sparse checkpoints (``VikinBackend(masks=...)``,
@@ -262,9 +267,15 @@ class TransformerBackend(ModelBackend):
                 params)
         self.cfg, self.params = cfg, params
         self.precision = precision
-        self._T, self._jax = T, jax
+        from repro.models.moe import total_counts
+
+        self._T, self._jax, self._total_counts = T, jax, total_counts
+        # the jitted programs return the MoE counts beside the logits
+        # (models/moe.total_counts: empty for an arch without MoE layers)
         def transformer_decode(p: Any, tok: Any, c: Any) -> Any:
-            return T.decode_step(p, cfg, tok, c)
+            counters: List[Any] = []
+            logits, c = T.decode_step(p, cfg, tok, c, counters=counters)
+            return logits, c, total_counts(counters)
 
         self._decode = jax.jit(transformer_decode)
 
@@ -309,7 +320,11 @@ class TransformerBackend(ModelBackend):
             cfg, T = self.cfg, self._T
 
             def transformer_prefill(params: Any, tokens: Any) -> Any:
-                return T.prefill(params, cfg, tokens, max_len=self.max_len)
+                counters: List[Any] = []
+                logits, cache = T.prefill(params, cfg, tokens,
+                                          max_len=self.max_len,
+                                          counters=counters)
+                return logits, cache, self._total_counts(counters)
 
             self._prefill_cache[length] = self._jax.jit(transformer_prefill)
         return self._prefill_cache[length]
@@ -320,27 +335,32 @@ class TransformerBackend(ModelBackend):
         import jax.numpy as jnp
 
         return self._prefill_fn(tokens.shape[1])(
-            self.params, jnp.asarray(tokens, jnp.int32))
+            self.params, jnp.asarray(tokens, jnp.int32))[:2]
 
     def prefill(self, caches: Any, slot: int, req: Request) -> Any:
         """Prefill one request and splice its (batch=1) cache into lane
         ``slot`` of the server's (batch=n_slots) caches."""
+        import jax.numpy as jnp
+
         jax, T = self._jax, self._T
         tokens = np.asarray(req.prompt, np.int32)[None, :]
         if self.layers is not None:
             # each prefilled prompt token is one model instance the cycle
             # model must charge on the NEXT tick's report
             self._pending_prefill += tokens.shape[1]
-        with span("backend.prefill", rid=req.rid, tokens=tokens.shape[1]):
+        with span("backend.prefill", rid=req.rid,
+                  tokens=tokens.shape[1]) as sp:
             with span("backend.prefill.dispatch"):
-                logits, cache = self.prefill_logits(tokens)
+                logits, cache, counts = self._prefill_fn(tokens.shape[1])(
+                    self.params, jnp.asarray(tokens, jnp.int32))
             with span("backend.prefill.pick"):
                 first = T.greedy_token(logits)
             with span("backend.prefill.splice"):
                 caches = self._splice(caches, cache, np.int32(slot))
             with span("backend.prefill.readback"):
-                next_tok = int(jax.device_get(first)[0, 0])
-            req.generated.append(next_tok)
+                first, counts = jax.device_get((first, counts))
+            sp.count(**counts)
+            req.generated.append(int(first[0, 0]))
         return caches
 
     def step(self, caches: Any,
@@ -349,18 +369,21 @@ class TransformerBackend(ModelBackend):
 
         jax, T = self._jax, self._T
         active = [s for s, r in enumerate(slot_req) if r is not None]
-        with span("backend.step", active=len(active), computed=self.n_slots):
+        with span("backend.step", active=len(active),
+                  computed=self.n_slots) as sp:
             with span("backend.step.inputs"):
                 toks = np.zeros((self.n_slots, 1), np.int32)
                 for s in active:
                     toks[s, 0] = slot_req[s].generated[-1]
                 toks = jnp.asarray(toks)
             with span("backend.step.dispatch"):
-                logits, caches = self._decode(self.params, toks, caches)
+                logits, caches, counts = self._decode(self.params, toks,
+                                                      caches)
             with span("backend.step.pick"):
                 picked = T.greedy_token(logits)
             with span("backend.step.readback"):
-                nxt = np.asarray(jax.device_get(picked))
+                nxt, counts = jax.device_get((picked, counts))
+            sp.count(**counts)
             with span("backend.step.outputs"):
                 for s in active:
                     req = slot_req[s]

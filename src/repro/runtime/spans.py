@@ -14,6 +14,9 @@ profiler records (``jax.profiler.TraceAnnotation.is_enabled()``): under
   spans gives each its parent), and ``info``.
 * Off, ``span`` is one flag check returning a shared no-op context
   manager: no clock read, nothing recorded.
+* ``with span(...) as s: ... s.count(**counts)`` adds counts known only
+  at the phase's end (read back from the device) to its record; off, a
+  no-op.
 
 ``record`` keeps a span whose start lies in the past (a request's queue
 wait, from its submit stamp); it is in the ring only, since the profiler's
@@ -71,6 +74,9 @@ class _Off:
     def __exit__(self, *exc: object) -> None:
         return None
 
+    def count(self, **counts: Any) -> None:
+        return None
+
 
 _OFF = _Off()
 
@@ -92,6 +98,12 @@ class _On:
         rec._stack.append(self._index)
         self._t0 = _clock()
         return self
+
+    def count(self, **counts: Any) -> None:
+        """Add counts known only once the phase has run (read back from
+        the device) to the span's record; the trace's event keeps the
+        counts given at its start."""
+        self._info.update((k, int(v)) for k, v in counts.items())
 
     def __exit__(self, *exc: object) -> None:
         t1 = _clock()

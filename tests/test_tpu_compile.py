@@ -22,6 +22,11 @@ qwen2-0.5b-kanffn width, ``vikin_forward`` of vikin-mixed): the device
 trace's readers find the kernels by the instruction names checked here.
 So is the prefill's cache splice, ``transformer_splice``, at the served
 slot caches, with the slot a runtime argument.
+
+The grouped KAN kernel of the KAN-FFN expert layer (``kan_grouped_pallas``)
+compiles at qwen3-moe-235b-a22b-kanffn's expert widths (4096 -> 341, 32
+held experts) for 128 and 4096 routed rows, and the served prefill and
+decode of that arch hold it under its name.
 """
 import dataclasses
 import functools
@@ -38,6 +43,12 @@ from repro.configs.vikin_models import VIKIN_ARCHS
 from repro.core.splines import SplineSpec
 from repro.kernels import autotune
 from repro.kernels.kan_fused import ops as kan_ops
+from repro.kernels.kan_fused.grouped import (
+    grouped_blocks,
+    grouped_vmem_bytes,
+    kan_grouped_pallas,
+    tile_rows,
+)
 from repro.kernels.kan_fused.kan_fused import (
     VMEM_LIMIT,
     kan_fused_pallas_v2,
@@ -51,6 +62,7 @@ from repro.kernels.pattern_matmul.pattern_matmul import (
 )
 from repro.models import transformer as T
 from repro.models.ffn import stack_layer_cfgs, vikin_stack_init
+from repro.models.moe import stored_hidden
 from repro.runtime.backends import TransformerBackend, VikinBackend
 
 
@@ -230,3 +242,57 @@ def test_splice_is_one_program_with_the_slot_a_runtime_argument(one_chip):
     slot_params = [line for line in params
                    if re.search(r"= s32\[\]\S* parameter\(", line)]
     assert len(slot_params) == 1 and 'op_name="slot"' in slot_params[0]
+
+
+MOE_ARCH = "qwen3-moe-235b-a22b-kanffn"
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rows", [128, 4096])
+def test_kan_grouped_compiles(one_chip, rows, dtype):
+    """The grouped kernel at the served expert widths, its blocks chosen
+    as the layer chooses them: the row tile from the rows each of the 32
+    held experts expects, the rest from the VMEM budget."""
+    arch = KANFFN_ARCHS[MOE_ARCH]
+    moe = arch.moe_cfg()
+    d, held, nbk = arch.d_model, moe.held, SPEC.n_bases
+    h = stored_hidden(moe.expert_ffn_cfg().kanffn_hidden)
+    bm = tile_rows(rows / held, dtype)
+    bi, bn = grouped_blocks(bm, d, h, nbk, dtype)
+    assert grouped_vmem_bytes(bm, bi, bn, nbk, dtype) <= VMEM_LIMIT
+    x = jax.ShapeDtypeStruct((rows, d), dtype, sharding=one_chip)
+    wt = jax.ShapeDtypeStruct((held, h, d * (nbk + 1)), dtype,
+                              sharding=one_chip)
+    te = jax.ShapeDtypeStruct((rows // bm,), jnp.int32, sharding=one_chip)
+    n_used = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = _compile(lambda x, w, te, n: kan_grouped_pallas(
+        x, w, te, n, SPEC, bm=bm, bi=bi, bn=bn), x, wt, te, n_used)
+    # the weights reach the kernel as they are stored: no copy per call
+    assert " copy(" not in "".join(
+        line for line in compiled.as_text().splitlines()
+        if f"[{held},{h},{d * (nbk + 1)}]" in line.split(" = ", 1)[-1][:40])
+
+
+@pytest.mark.parametrize("name", ["transformer_prefill",
+                                  "transformer_decode"])
+def test_moe_served_program_holds_the_grouped_kernel(one_chip, name):
+    """The served MoE arch's programs keep their names and the grouped
+    kernel under the instruction name ``moe_roofline`` searches for."""
+    arch = dataclasses.replace(KANFFN_ARCHS[MOE_ARCH], dtype="bfloat16")
+    params = jax.eval_shape(functools.partial(T.init_params, cfg=arch),
+                            jax.random.key(0))
+    b = TransformerBackend(arch, _on(one_chip, params), impl="pallas",
+                           precision="bf16")
+    b.n_slots, b.max_len = 8, 64
+    if name == "transformer_prefill":
+        toks = jax.ShapeDtypeStruct((1, 16), jnp.int32, sharding=one_chip)
+        fn, args = b._prefill_fn(16), (b.params, toks)
+    else:
+        caches = jax.eval_shape(lambda: T.init_caches(b.cfg, 8, 64))
+        toks = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
+        fn, args = b._decode, (b.params, toks, _on(one_chip, caches))
+    text = fn.lower(*args).compile().as_text()
+    assert text.startswith(f"HloModule jit_{name},")
+    instrs = [line.split(" = ")[0] for line in text.splitlines()
+              if " = " in line]
+    assert sum("kan_grouped_pallas" in i for i in instrs) == arch.n_layers
